@@ -138,6 +138,9 @@ def run(quick: bool = False) -> dict:
                  if not f.startswith("--xla_force_host_platform_device_count")]
     env["XLA_FLAGS"] = " ".join(
         ["--xla_force_host_platform_device_count=8"] + inherited)
+    # eight forced host devices are CPU devices: the child must never
+    # reach for a chip this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = (os.path.join(repo, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))

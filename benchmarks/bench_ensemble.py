@@ -14,11 +14,12 @@ _slot``) so the slots × shards variant — each slot's grid decomposed over
 a "shard" mesh axis — lands in ``BENCH_*.json`` directly comparable to
 the undecomposed rows (same sim-steps/sec unit, explicit block size).
 
-``--backend pallas`` runs the same matrix on the Pallas 3DBLOCK path
-(resolved to ``pallas-interpret`` on non-TPU hosts — the correctness
-mode, NOT a speed claim) and emits ``BENCH_ensemble_pallas.json``: its
-structural fields — farm-vs-serial bitwise parity, one compiled
-executable per static signature, a throughput row per ensemble size —
+``--backend pallas-interpret`` runs the same matrix on the Pallas 3DBLOCK
+path through the interpreter (the correctness mode, NOT a speed claim;
+``--backend pallas`` needs a TPU and raises elsewhere) and emits
+``BENCH_ensemble_pallas.json``: its structural fields — farm-vs-serial
+parity to float32 ulps, one compiled executable per static signature, a
+throughput row per ensemble size —
 are gated by ``benchmarks/check_regression.py`` on every CI push, so
 the farm's Pallas backend cannot silently regress to literal-baking or
 per-scalar recompiles between real-hardware runs.
@@ -33,34 +34,45 @@ FIELDS = ("vx", "vy", "vz", "p")
 
 
 def resolve_backend(backend: str) -> str:
-    """``pallas`` needs TPU hardware; everywhere else the interpret mode
-    runs the same kernels (and the same scalar-table machinery)."""
+    """The backend the bench runs.  ``pallas`` compiles for the TPU only:
+    off the TPU it raises instead of quietly measuring the interpreter
+    (ask for ``pallas-interpret`` by name for that)."""
     import jax
 
     if backend == "pallas" and jax.default_backend() != "tpu":
-        return "pallas-interpret"
+        raise ValueError(
+            f"backend 'pallas' needs a TPU, this host runs "
+            f"{jax.default_backend()!r}; use 'pallas-interpret'")
     return backend
 
 
-def _parity_check(farm_rt, serial_rt, steps: int = 6) -> bool:
-    """One heterogeneous pair, farm vs serial, bitwise — the structural
-    claim of the scalar-table design, embedded in the artifact."""
+# 8 ulps of the O(1) lid velocity: interpret mode cannot hold farm and
+# serial bitwise (XLA:CPU's fusion emitters round the interpreted grid
+# loop differently once it gains a slot axis), while a wrong per-slot
+# scalar row moves the fields by orders of magnitude more
+PARITY_ATOL = 8 * float(np.finfo(np.float32).eps)
+
+
+def _parity_check(farm_rt, serial_rt, steps: int = 6) -> dict:
+    """One heterogeneous pair, farm vs serial — the structural claim of
+    the scalar-table design, embedded in the artifact."""
     import jax
 
     sids = [farm_rt.submit("cavity", re=re, steps=steps)
             for re in (123.0, 321.0)]
     out = farm_rt.drain()
-    ok = True
+    diff = 0.0
     for sid, re in zip(sids, (123.0, 321.0)):
         pr = serial_rt.prepare("cavity", re=re)
         st = pr.state
         for _ in range(steps):
             st = pr.step(st)
         st = jax.device_get(st)
-        ok &= all(np.array_equal(np.asarray(st[f]),
-                                 np.asarray(out[sid].state[f]))
-                  for f in FIELDS)
-    return bool(ok)
+        diff = max([diff] + [float(np.abs(np.asarray(st[f])
+                                          - np.asarray(out[sid].state[f])
+                                          ).max()) for f in FIELDS])
+    return {"max_abs_diff": diff, "bitwise": diff == 0.0,
+            "ok": diff <= PARITY_ATOL}
 
 
 def _bench_serial(rt, res_values, steps):
@@ -135,7 +147,7 @@ def run(n: int = 16, steps: int = 80, quick: bool = False, repeats: int = 2,
 
     ``backend`` selects the kernel template (``api.BACKENDS``); the
     Pallas variants additionally record the structural fields the CI
-    regression gate pins: bitwise farm-vs-serial parity and the compile
+    regression gate pins: farm-vs-serial parity and the compile
     -cache miss count (one executable per static signature).
     """
     from repro import api
@@ -196,11 +208,11 @@ def run(n: int = 16, steps: int = 80, quick: bool = False, repeats: int = 2,
         parity_rt = api.runtime(n=n, n_slots=4, jacobi_iters=20,
                                 backend=resolved)
         serial_rt = api.runtime(n=n, jacobi_iters=20, backend=resolved)
-        out["parity"] = {"bitwise_ok": _parity_check(parity_rt, serial_rt)}
+        out["parity"] = _parity_check(parity_rt, serial_rt)
         out["expected_compile_misses"] = expected
         out["compile_cache"] = api.compile_cache_stats()
         out["passed"] = bool(
-            out["passed"] and out["parity"]["bitwise_ok"]
+            out["passed"] and out["parity"]["ok"]
             and out["compile_cache"]["misses"] == expected)
     return out
 
@@ -211,8 +223,8 @@ def main(argv=None) -> int:
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default="jnp",
-                    help="kernel backend (api.BACKENDS); 'pallas' falls "
-                         "back to interpret mode off-TPU")
+                    help="kernel backend (api.BACKENDS); 'pallas' "
+                         "needs a TPU")
     ap.add_argument("--n", type=int, default=16)
     ap.add_argument("--steps", type=int, default=80)
     ap.add_argument("--repeats", type=int, default=2)

@@ -60,6 +60,9 @@ def run(block: int = 32, devices=(1, 2, 4, 8), quick: bool = False) -> dict:
     rows = []
     for n in devices:
         env = dict(os.environ)
+        # forced host devices are CPU devices: the child must never reach
+        # for a chip this process may hold
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={max(n,1)}"
         env["PYTHONPATH"] = os.path.join(REPO, "src")
         proc = subprocess.run(

@@ -18,9 +18,9 @@ Failure conditions (exit 1, CI-red):
 * any fresh perf row reports a halo-byte MISMATCH or turned
   ``unparsed`` relative to its baseline row;
 * a ``BENCH_ensemble_pallas.json`` artifact breaks a structural
-  invariant — farm-vs-serial bitwise parity, one compiled executable
-  per static signature, a throughput row per ensemble size — gated
-  baseline-free on any host (``structural_failures``).
+  invariant — farm-vs-serial parity to float32 ulps, one compiled
+  executable per static signature, a throughput row per ensemble size —
+  gated baseline-free on any host (``structural_failures``).
 
 When the throughput gate trips, the perf attribution explains *why* by
 diffing the predicted-cost rows: measured seconds up with predicted
@@ -114,9 +114,9 @@ def structural_failures(fresh: dict) -> list[str]:
     """Host-independent invariants, gated without any baseline, on any
     machine.
 
-    ``ensemble_pallas``: the farm really ran the Pallas template, stayed
-    bitwise with serial, and compiled exactly one executable per static
-    signature.  ``smoke``: the health monitor's modeled steady-state
+    ``ensemble_pallas``: the farm really ran the Pallas template, matched
+    serial runs to float32 ulps, and compiled exactly one executable per
+    static signature.  ``smoke``: the health monitor's modeled steady-state
     cost within ``HEALTH_OVERHEAD`` of the health-off step, and ring
     drains exactly on the harvest cadence.  ``health_smoke``: the
     NaN-injection quarantine
@@ -146,9 +146,9 @@ def structural_failures(fresh: dict) -> list[str]:
         if not (isinstance(r, dict) and r.get("farm_steps_per_s", 0) > 0):
             fails.append(f"ensemble_pallas: ensemble={r.get('ensemble')} "
                          "row has no farm throughput")
-    if m.get("parity", {}).get("bitwise_ok") is not True:
-        fails.append("ensemble_pallas: farm-vs-serial bitwise parity did "
-                     "not hold (scalar-table regression?)")
+    if m.get("parity", {}).get("ok") is not True:
+        fails.append("ensemble_pallas: farm-vs-serial parity did not hold "
+                     "(scalar-table regression?)")
     misses = m.get("compile_cache", {}).get("misses")
     if misses != m.get("expected_compile_misses"):
         fails.append(

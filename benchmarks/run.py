@@ -270,6 +270,8 @@ def run_durability_smoke(out_dir: str) -> dict:
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    # the child runs on the CPU: a chip this process holds is not shared
+    env["JAX_PLATFORMS"] = "cpu"
     child = subprocess.run(
         [sys.executable, "-c",
          _DURABILITY_CHILD.format(n=n, steps=steps, store=store_path)],
@@ -362,6 +364,9 @@ def main():
                     help="where BENCH_*.json artifacts land")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if args.smoke or args.health_smoke or args.durability_smoke:
         ok = True
         if args.smoke:

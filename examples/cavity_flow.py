@@ -10,8 +10,11 @@ import argparse
 
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=48)
     ap.add_argument("--t-end", type=float, default=12.0)

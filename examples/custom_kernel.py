@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import generate, parse_ccl
+from repro.launch.compile_cache import use_compile_cache
 
 CCL = """
 CCTK_CUDA_KERNEL GRADIENT_MAG
@@ -32,6 +33,7 @@ CCTK_CUDA_KERNEL GRADIENT_MAG
 
 
 def main():
+    use_compile_cache()
     desc = parse_ccl(CCL)[0]
     print(f"parsed descriptor: {desc.name}, stencil={desc.stencil}, "
           f"tile={desc.tile}")

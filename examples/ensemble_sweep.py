@@ -17,8 +17,11 @@ Perfetto.  ``--report`` prints the Cactus-style timer/metrics summary.
 import argparse
 import time
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=24)
     ap.add_argument("--slots", type=int, default=4)

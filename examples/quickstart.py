@@ -14,9 +14,11 @@ a device: that is the point.
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
 from repro import api
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     # the registry: every problem the runtime can serve by name
     print("registered scenarios:")
     for name in api.scenario_names():

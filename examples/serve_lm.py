@@ -6,8 +6,11 @@ Run:  PYTHONPATH=src python examples/serve_lm.py [--arch zamba2-1.2b]
 """
 import argparse
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--requests", type=int, default=8)

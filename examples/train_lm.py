@@ -12,8 +12,11 @@ Run:  PYTHONPATH=src python examples/train_lm.py [--steps 200]
 import argparse
 import sys
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")

@@ -1,7 +1,4 @@
 """Massively data-parallel stencil framework (CaCUDA on TPU) + LM stack."""
-from repro import compat as _compat  # installs jax version shims on import
-
-_compat.install()
 
 
 def __getattr__(name):

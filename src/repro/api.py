@@ -59,22 +59,18 @@ __all__ = [
 # through the generator's scalar-table operand (scalar prefetch on real
 # TPU), so farm runs under "pallas"/"pallas-interpret" share one compiled
 # kernel across heterogeneous slots and match "jnp" farms to tolerance
-# (and pallas-interpret serial runs bitwise).
-# "auto" resolves AT CONFIGURE TIME to "pallas" on TPU hosts and "jnp"
-# elsewhere — the resolved config always carries an explicit template,
-# never None (the solver would coerce None to JNP regardless of device).
+# (and pallas-interpret serial runs to float32 ulps).
+# "auto" resolves to "jnp" on every host: the 3DBLOCK template does not
+# lower for the TPU yet, and an explicit "pallas" there fails with the
+# compiler's error (serially) or as terminated="failed" farm results —
+# it never falls back to the interpreter or the jnp oracle.
 BACKENDS = {
     "jnp": ("JNP", False, None),            # fused-XLA template (CPU/TPU)
     "pallas-interpret": ("3DBLOCK", True, False),  # Pallas tiles, interpret
     "pallas": ("3DBLOCK", False, False),    # Pallas tiles on real hardware
-    "auto": None,                           # device default, resolved late
+    # jnp until tests/test_chip_compile.py::test_3dblock_jacobi_compiles
+    "auto": ("JNP", False, None),
 }
-
-
-def _resolve_backend(name: str) -> tuple:
-    if name == "auto":
-        name = "pallas" if jax.default_backend() == "tpu" else "jnp"
-    return BACKENDS[name]
 
 
 def compile_cache_stats() -> dict:
@@ -252,7 +248,7 @@ class Runtime:
         resolution (a different static signature, hence — on the farm
         path — a different lazily-built service)."""
         sc = get_scenario(scenario)
-        template, interpret, overlap = _resolve_backend(self.config.backend)
+        template, interpret, overlap = BACKENDS[self.config.backend]
         builder_kw = dict(self.config.solver)
         if self.config.nz is not None:
             builder_kw["nz"] = self.config.nz
@@ -320,7 +316,12 @@ class Runtime:
                 # full field state for the whole run
                 prev = state if (residual_tol is not None
                                  and (i + 1) % check == 0) else None
-                state = pr.step(state)
+                state, last = pr.step(state), state
+                # wait for the previous step once this one is queued: each
+                # queued step holds its own output state, and unbounded
+                # run-ahead fills the device (16 GB after 400 steps at
+                # 256^3 on a v5e) without making the run any faster
+                jax.block_until_ready(last)
                 done = i + 1
                 if progress and (done % progress == 0):
                     print(f"  step {done:6d}/{steps} "

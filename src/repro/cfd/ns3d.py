@@ -185,10 +185,11 @@ class NavierStokes3D:
             mx[-1, :, :] = 0.0
             my[:, -1, :] = 0.0
             # z periodic: no vz mask
-        arrs = [jnp.asarray(m) for m in (mx, my, mz)]
+        # host -> shards directly: staging through jnp.asarray would put
+        # the whole grid on the default device first
         if sh is not None:
-            arrs = [jax.device_put(a, sh) for a in arrs]
-        return arrs
+            return [jax.device_put(m, sh) for m in (mx, my, mz)]
+        return [jnp.asarray(m) for m in (mx, my, mz)]
 
     # ----------------------------------------------------------------- step
     def _global_mean(self, x):
@@ -306,7 +307,8 @@ class NavierStokes3D:
         same parameters on every template.
         """
         c = self.config
-        example = self.init_state()
+        # the state's tree structure only: no second set of fields
+        example = jax.eval_shape(self.init_state)
         params = params_from_config(c)
         jstep = self.driver.sharded_step_tree(self._step_local, example, params)
         return lambda s: jstep(s, params)

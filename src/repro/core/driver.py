@@ -87,23 +87,30 @@ class GridDriver:
 
     # -- storage ------------------------------------------------------------
     def coords(self) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-        """Global cell-center coordinate arrays (sharded like fields)."""
+        """Global cell-center coordinate arrays (sharded like fields).
+
+        Built already sharded: each device computes only its own block,
+        so no device ever holds a whole global grid.
+        """
         axes = [
             self.domain.origin[a] + (np.arange(self.domain.shape[a]) + 0.5) * self.domain.spacing[a]
             for a in range(3)
         ]
-        grids = jnp.meshgrid(*[jnp.asarray(x) for x in axes], indexing="ij")
-        if self.mesh is not None:
-            grids = [jax.device_put(g, self.sharding()) for g in grids]
-        return tuple(grids)
+
+        def grids():
+            return tuple(jnp.meshgrid(*[jnp.asarray(x) for x in axes],
+                                      indexing="ij"))
+
+        sh = self.sharding()
+        if sh is None:
+            return grids()
+        return jax.jit(grids, out_shardings=(sh,) * 3)()
 
     def allocate(self, names: Sequence[str], init=0.0, dtype=jnp.float32) -> dict:
+        """Constant-filled fields, created directly in their sharding."""
         sh = self.sharding()
-        out = {}
-        for n in names:
-            arr = jnp.full(self.domain.shape, init, dtype=dtype)
-            out[n] = jax.device_put(arr, sh) if sh is not None else arr
-        return out
+        return {n: jnp.full(self.domain.shape, init, dtype=dtype, device=sh)
+                for n in names}
 
     # -- execution ----------------------------------------------------------
     def sharded_step(self, step_local: Callable, n_fields_out: int | None = None):

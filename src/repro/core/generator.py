@@ -14,7 +14,8 @@ drives two templates:
 * ``JNP`` — a fused pure-``jnp`` expansion of the same body (shifted slices
   of the padded array).  It is the oracle for kernel tests, the
   shape-polymorphic kernel used for boundary shells in overlap mode, and the
-  XLA path on non-TPU backends.
+  default XLA path on every backend (3DBLOCK does not lower for the TPU
+  yet: ``tests/test_chip_compile.py``).
 
 The *kernel body* the user writes is a function ``body(ctx) -> dict`` where
 ``ctx[name]`` is a :class:`FieldView` supporting ``.at(dx, dy, dz)`` shifted
@@ -49,26 +50,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # newer JAX: per-dim element indexing via Element block dims
-    from jax._src.pallas.core import Element as _Element
-except ImportError:  # older JAX: whole-spec Unblocked indexing mode
-    _Element = None
-
-# This JAX version ships optimization_barrier without a batching rule; the
-# rule is the identity on batch dims (the barrier is shape-transparent), as
-# added upstream in later releases.  Needed because interpret-mode kernels
-# pin their operand/result boundary with a barrier (see _apply_pallas) and
-# literal-parameter kernels batch through pallas's native vmap rule.
-try:
-    from jax.interpreters import batching as _batching
-    _ob_p = jax._src.lax.lax.optimization_barrier_p
-    if _ob_p not in _batching.primitive_batchers:
-        def _ob_batcher(batched_args, batch_dims, **params):
-            return _ob_p.bind(*batched_args, **params), batch_dims
-        _batching.primitive_batchers[_ob_p] = _ob_batcher
-except (ImportError, AttributeError):  # pragma: no cover - newer JAX has it
-    pass
-
 from repro.core.descriptor import Intent, StencilDescriptor
 
 
@@ -89,14 +70,10 @@ def element_block_spec(block_shape, index_map) -> pl.BlockSpec:
     """BlockSpec whose ``index_map`` returns *element* offsets.
 
     This is how the 3DBLOCK template expresses halo-expanded overlapping
-    windows (tile + stencil) staged into VMEM.  Newer JAX spells it with
-    ``Element`` block dims; older JAX with the ``Unblocked`` indexing mode.
-    Both take element offsets from the index map, so callers are agnostic.
+    windows (tile + stencil) staged into VMEM: every block dim is a
+    ``pl.Element``, so the index map returns element (not block) offsets.
     """
-    if _Element is not None:
-        return pl.BlockSpec(tuple(_Element(b) for b in block_shape), index_map)
-    return pl.BlockSpec(tuple(block_shape), index_map,
-                        indexing_mode=pl.Unblocked())
+    return pl.BlockSpec(tuple(pl.Element(b) for b in block_shape), index_map)
 
 
 class FieldView:

@@ -5,8 +5,9 @@ analyzer (terms from compiled HLO), the perf accounting layer
 (``repro.obs.perf``), and the benchmark harness.  Chips live in a small
 registry so utilization is always reported against the peaks of the
 hardware that actually ran — ``resolve_chip("auto")`` picks the entry
-matching ``jax.devices()`` (a CI CPU lane reports against host-class
-peaks, not TPU v5e ones).
+matching ``jax.devices()[0]`` (TPUs by ``device_kind``; a CI CPU lane
+reports against host-class peaks) and raises for any device it has no
+peaks for.
 """
 from __future__ import annotations
 
@@ -28,6 +29,8 @@ class Chip:
         return self.peak_flops_bf16 if dtype in ("bf16", "bfloat16") else self.peak_flops_fp32
 
 
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of inter-chip interconnect (4 links x 50 GB/s)
 V5E = Chip()
 
 # Deliberately round host-class numbers (a few vector cores of XLA:CPU,
@@ -47,44 +50,43 @@ CPU_HOST = Chip(
     vmem_bytes=32 * 2**20,     # L2/L3-class working set
 )
 
-# GPUs only appear through jax.default_backend() == "gpu"; an A100-class
-# placeholder keeps "auto" total rather than precise.
-GPU_GENERIC = Chip(
-    name="gpu-generic",
-    peak_flops_bf16=312e12,
-    peak_flops_fp32=19.5e12,
-    hbm_bandwidth=1.6e12,
-    hbm_bytes=40e9,
-    ici_link_bandwidth=100e9,
-    ici_links=2,
-    vmem_bytes=40 * 2**20,
-)
-
 CHIPS: dict[str, Chip] = {
     "tpu-v5e": V5E,
     "cpu-host": CPU_HOST,
-    "gpu-generic": GPU_GENERIC,
 }
 
-_PLATFORM_CHIP = {"tpu": "tpu-v5e", "cpu": "cpu-host", "gpu": "gpu-generic",
-                  "cuda": "gpu-generic", "rocm": "gpu-generic"}
+# jax device_kind -> registry name; a TPU generation absent here has no
+# peaks in this repo and must not borrow another's
+_TPU_KIND_CHIP = {"TPU v5 lite": "tpu-v5e"}
+
+
+def chip_for_device(platform: str, device_kind: str) -> Chip:
+    """The registry entry for one ``jax.Device`` (its ``platform`` and
+    ``device_kind``); raises for a device the registry has no peaks for."""
+    if platform == "tpu" and device_kind in _TPU_KIND_CHIP:
+        return CHIPS[_TPU_KIND_CHIP[device_kind]]
+    if platform == "cpu":
+        return CPU_HOST
+    raise KeyError(f"no peaks for device {device_kind!r} on platform "
+                   f"{platform!r} (known TPU kinds: {sorted(_TPU_KIND_CHIP)})")
 
 
 def resolve_chip(spec: "Chip | str | None" = "auto") -> Chip:
     """Coerce a chip spec to hardware constants.
 
     Accepts a :class:`Chip` (passes through), a registry name
-    (``"tpu-v5e"``, ``"cpu-host"``, ...), or ``"auto"``/``None`` — which
-    resolves from the platform of ``jax.devices()[0]`` so CI CPU numbers
-    are never reported against TPU peaks.
+    (``"tpu-v5e"``, ``"cpu-host"``), or ``"auto"``/``None`` — which
+    resolves from ``jax.devices()[0]`` through :func:`chip_for_device`,
+    so CI CPU numbers are never reported against TPU peaks and an unknown
+    device is an error, not a default.
     """
     if isinstance(spec, Chip):
         return spec
     if spec is None or spec == "auto":
         import jax
 
-        platform = jax.devices()[0].platform
-        return CHIPS[_PLATFORM_CHIP.get(platform, "cpu-host")]
+        dev = jax.devices()[0]
+        return chip_for_device(dev.platform, dev.device_kind)
     if spec in CHIPS:
         return CHIPS[spec]
     raise KeyError(f"unknown chip {spec!r} (have {sorted(CHIPS)} or 'auto')")

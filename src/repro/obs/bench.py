@@ -3,8 +3,8 @@
 The ROADMAP's bench trajectory is a series of ``BENCH_<name>.json``
 artifacts, one per benchmark run, comparable across PRs because every
 file carries the same envelope: schema version, bench name, creation
-time, host fingerprint (backend, device count, versions), pass verdict,
-wall time, and the bench's own numbers under ``metrics``.
+time, host fingerprint (backend, device kind and count, versions), pass
+verdict, wall time, and the bench's own numbers under ``metrics``.
 ``benchmarks/run.py`` emits them; CI schema-validates and archives the
 ``--smoke`` artifact on every push, so a malformed entry can never enter
 the trajectory silently.
@@ -41,6 +41,7 @@ def host_info() -> dict:
 
     return {
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
         "device_count": jax.device_count(),
         "python": platform.python_version(),
         "jax": jax.__version__,

@@ -205,7 +205,8 @@ def decomposed_step_hlo(config, *, n_slots: int, mesh_axes,
     from repro.cfd.ns3d import PARAM_KEYS, NavierStokes3D
     from repro.sim.ensemble import make_ensemble_step, plan_decomposition
 
-    mesh = AbstractMesh(tuple(mesh_axes))
+    mesh = AbstractMesh(tuple(e for _, e in mesh_axes),
+                        tuple(n for n, _ in mesh_axes))
     solver_cfg, active = plan_decomposition(config, mesh,
                                             slot_axis=slot_axis)
     # the AbstractMesh satisfies the driver's axis-name/divisibility checks;

@@ -40,14 +40,3 @@ def _multidevice_per_test_timeout(request):
     finally:
         signal.alarm(0)
         signal.signal(signal.SIGALRM, old)
-
-# The suite must collect on a bare interpreter (pytest + jax only).  Prefer
-# the real hypothesis; otherwise install the deterministic fallback so the
-# property tests still run their sweeps instead of crashing at import.
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    from tests import _hypothesis_fallback
-
-    sys.modules["hypothesis"] = _hypothesis_fallback
-    sys.modules["hypothesis.strategies"] = _hypothesis_fallback.strategies

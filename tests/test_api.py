@@ -92,20 +92,21 @@ class TestRegistry:
 # schedule-bin ordering (hypothesis property)
 # ---------------------------------------------------------------------------
 def _entries_strategy():
-    """Up to 7 named entries with random before/after constraints drawn
-    only against *earlier* entries — a DAG by construction."""
-    def build(n, edges):
+    """Up to 7 named entries with before/after constraints drawn only
+    against *earlier* entries, each pointing the way a hidden random
+    total order (``rank``) says — a DAG by construction."""
+    def build(n, edges, rank):
         out = []
         for i in range(n):
-            befores = tuple(f"e{j}" for j in range(i) if (i, j, 0) in edges)
-            afters = tuple(f"e{j}" for j in range(i) if (i, j, 1) in edges)
+            js = [j for j in range(i) if (i, j) in edges]
+            befores = tuple(f"e{j}" for j in js if rank[i] < rank[j])
+            afters = tuple(f"e{j}" for j in js if rank[i] > rank[j])
             out.append((f"e{i}", befores, afters))
         return out
 
-    edge = st.tuples(st.integers(0, 6), st.integers(0, 6),
-                     st.integers(0, 1))
-    return st.builds(build, st.integers(1, 7),
-                     st.sets(edge, max_size=8))
+    edge = st.tuples(st.integers(0, 6), st.integers(0, 6))
+    return st.builds(build, st.integers(1, 7), st.sets(edge, max_size=8),
+                     st.permutations(range(7)))
 
 
 class TestScheduleOrdering:

@@ -5,6 +5,7 @@ that telemetry off is bitwise-invisible."""
 import json
 import threading
 
+import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -384,8 +385,9 @@ class TestBenchSchema:
         loaded = obs.load_bench(path)
         assert loaded["metrics"]["speedup"] == 2.5
         assert loaded["schema"] == obs.BENCH_SCHEMA
-        for f in ("backend", "device_count", "python", "jax"):
+        for f in ("backend", "device_kind", "device_count", "python", "jax"):
             assert f in loaded["host"]
+        assert loaded["host"]["device_kind"] == jax.devices()[0].device_kind
 
     def test_malformed_documents_are_named(self):
         good = obs.make_bench_doc("x", {}, passed=False, wall_s=0.0)

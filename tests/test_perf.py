@@ -11,7 +11,9 @@ from hypothesis import given, settings, strategies as st
 from benchmarks.check_regression import compare
 from repro import api, obs
 from repro.cfd.ns3d import CFDConfig
-from repro.core.rooflinemodel import CHIPS, V5E, Chip, resolve_chip
+from repro.core.rooflinemodel import (
+    CHIPS, V5E, Chip, chip_for_device, resolve_chip,
+)
 from repro.launch import hlo_cost
 from repro.obs import perf
 from repro.sim import SimulationService
@@ -145,10 +147,26 @@ class TestChipRegistry:
     def test_auto_resolves_to_the_running_platform(self):
         import jax
 
+        dev = jax.devices()[0]
         chip = resolve_chip("auto")
-        assert chip is CHIPS[{"cpu": "cpu-host", "tpu": "tpu-v5e"}.get(
-            jax.devices()[0].platform, "gpu-generic")]
+        assert chip is chip_for_device(dev.platform, dev.device_kind)
         assert resolve_chip(None) is chip
+
+    @pytest.mark.parametrize("platform,kind,name", [
+        ("tpu", "TPU v5 lite", "tpu-v5e"),
+        ("cpu", "cpu", "cpu-host"),
+    ])
+    def test_devices_resolve_by_kind(self, platform, kind, name):
+        assert chip_for_device(platform, kind) is CHIPS[name]
+
+    @pytest.mark.parametrize("platform,kind", [
+        ("tpu", "TPU v4"),          # a TPU generation with no peaks here
+        ("tpu", "TPU v5"),          # v5p must not borrow v5e's numbers
+        ("gpu", "NVIDIA A100-SXM4-40GB"),
+    ])
+    def test_unknown_devices_raise(self, platform, kind):
+        with pytest.raises(KeyError, match="no peaks"):
+            chip_for_device(platform, kind)
 
     def test_names_and_passthrough(self):
         assert resolve_chip("tpu-v5e") is V5E
@@ -295,7 +313,7 @@ class TestRegressionGate:
             "resolved_backend": "pallas-interpret",
             "batches": [{"ensemble": 1, "farm_steps_per_s": 100.0},
                         {"ensemble": 4, "farm_steps_per_s": 300.0}],
-            "parity": {"bitwise_ok": True},
+            "parity": {"ok": True},
             "expected_compile_misses": 3,
             "compile_cache": {"misses": 3, "hits": 1, "entries": 3},
         }
@@ -310,9 +328,9 @@ class TestRegressionGate:
         assert v["passed"], v["failures"]
 
     def test_pallas_parity_break_fails_without_baseline(self):
-        v = compare(self._pallas_doc(parity={"bitwise_ok": False}), None)
+        v = compare(self._pallas_doc(parity={"ok": False}), None)
         assert not v["passed"]
-        assert any("bitwise parity" in f for f in v["failures"])
+        assert any("parity did not hold" in f for f in v["failures"])
 
     def test_pallas_per_scalar_recompile_fails(self):
         """Five scalars fragmenting into five executables is THE failure

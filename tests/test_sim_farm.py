@@ -189,14 +189,24 @@ class TestPallasFarmParity:
     scalar table (scalar prefetch on hardware), one compiled 3DBLOCK
     kernel for every slot.
 
-    Contract: a ``pallas-interpret`` farm run is BITWISE the
-    pallas-interpret *serial* run of the same request — slots carry
+    Contract: a ``pallas-interpret`` farm run matches the
+    pallas-interpret *serial* run of the same request to a few float32
+    ulps of the O(1) lid velocity (``PALLAS_ATOL``) — slots carry
     heterogeneous nu/dt/lid scalars, so any literal-baking regression
     (slot 0's physics smeared over the batch, or one kernel per scalar
-    tuple) shows immediately — and matches the JNP farm to fp tolerance
-    (separately compiled XLA programs contract FMAs differently; the
-    cross-template contract was always tolerance-level, as in
-    ``tests/test_kernels.py``)."""
+    tuple) moves fields by orders of magnitude more and shows
+    immediately — and matches the JNP farm to fp tolerance (separately
+    compiled XLA programs contract FMAs differently; the cross-template
+    contract was always tolerance-level, as in ``tests/test_kernels.py``).
+
+    Not bitwise: interpret mode lowers the kernel to an XLA loop over its
+    grid, and XLA:CPU's fusion emitters generate different arithmetic for
+    that loop body when the grid gains a slot axis (with
+    ``--xla_cpu_use_fusion_emitters=false`` the two agree bitwise)."""
+
+    # 8 ulps of 1.0 in float32: far below what a wrong per-slot scalar
+    # row moves a field, far above the emitters' ~1 ulp per-step drift
+    PALLAS_ATOL = 8 * float(np.finfo(np.float32).eps)
 
     RES = (50.0, 200.0, 400.0)
     STEPS = (12, 8, 15)
@@ -229,8 +239,9 @@ class TestPallasFarmParity:
             assert res.terminated == "steps", (res.terminated, res.error)
             ref = self._serial(cavity.config(N, re=re, **PKW), st)
             for f in FIELDS:
-                np.testing.assert_array_equal(
-                    ref[f], res.state[f], err_msg=f"re={re} field={f}")
+                np.testing.assert_allclose(
+                    res.state[f], ref[f], rtol=0, atol=self.PALLAS_ATOL,
+                    err_msg=f"re={re} field={f}")
 
     def test_cavity_farm_matches_jnp_farm(self, cavity_farms):
         psids, pres = cavity_farms["3DBLOCK"]
@@ -274,7 +285,8 @@ class TestPallasFarmParity:
         assert ra.steps_done == 24
         ref = self._serial(cavity.config(N, re=100.0, **PKW), 24)
         for f in FIELDS:
-            np.testing.assert_array_equal(ref[f], ra.state[f], err_msg=f)
+            np.testing.assert_allclose(ra.state[f], ref[f], rtol=0,
+                                       atol=self.PALLAS_ATOL, err_msg=f)
         assert svc.result(b).steps_done == 24
         assert svc.result(c).steps_done == 6
 

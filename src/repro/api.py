@@ -277,7 +277,12 @@ class Runtime:
         sched = sc.schedule(solver, ic=ic_kw)
         tel = self.telemetry if self.telemetry.enabled else None
         state = sched.compile_bin("INITIAL", telemetry=tel)({})
-        step = sched.compile_bin("EVOLVE", telemetry=tel)
+        evolve = sched.compile_bin("EVOLVE", telemetry=tel)
+
+        def step(st: dict) -> dict:
+            with obs.span("runtime.step"):
+                return evolve(st)
+
         pr = PreparedRun(scenario=sc, solver=solver, schedule=sched,
                          state=state, step=step, config=cfg)
         if self.telemetry.enabled:
@@ -309,7 +314,7 @@ class Runtime:
         check = max(int(self.config.check_every), 1)
         state, terminated, done = pr.state, "steps", 0
         ke_prev: float | None = None
-        with self.telemetry.section(f"run.{pr.scenario.name}"):
+        with self.telemetry.timers.section(f"run.{pr.scenario.name}"):
             for i in range(steps):
                 # snapshot only when this step lands on a residual check
                 # boundary — an unconditional snapshot would pin a second

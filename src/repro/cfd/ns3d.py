@@ -219,6 +219,12 @@ class NavierStokes3D:
         vmap* on a slots × shards farm mesh — the collectives batch over
         the unnamed slot axis, keeping every slot bitwise equal to its
         serial decomposed run.
+
+        Each stage runs in a ``jax.named_scope`` (``update_velocity``,
+        ``divergence``, ``jacobi``, ``project``; the ghost fills are
+        ``exchange_pad``), so every op of the compiled step carries its
+        stage in its metadata, serial, shard-mapped or vmapped: a device
+        profile splits the step's time by stage.
         """
         c = self.config
         if params is None:
@@ -245,32 +251,34 @@ class NavierStokes3D:
                                       **vel_params, **kw)
             return jnp.stack(out)
 
-        if c.overlap:
-            # pack the components on a leading axis; the deep interior runs
-            # without any ghost dependency (overlaps the ppermutes), shells
-            # are computed from the exchanged pack.
-            def pad_packed(pack):
-                return jnp.stack([
-                    exchange_pad(pack[i], (1, 1, 1), specs(f))
-                    for i, f in enumerate(("vx", "vy", "vz"))
-                ])
+        with jax.named_scope("update_velocity"):
+            if c.overlap:
+                # pack the components on a leading axis; the deep interior
+                # runs without any ghost dependency (overlaps the
+                # ppermutes), shells are computed from the exchanged pack.
+                def pad_packed(pack):
+                    return jnp.stack([
+                        exchange_pad(pack[i], (1, 1, 1), specs(f))
+                        for i, f in enumerate(("vx", "vy", "vz"))
+                    ])
 
-            packed = jnp.stack([vx, vy, vz])
-            out = stencil_step_overlap(
-                packed, (0, 1, 1, 1), specs=None, kernel=upd_packed,
-                pad_fn=pad_packed)
-            vx_s, vy_s, vz_s = out[0], out[1], out[2]
-        else:
-            pads = [exchange_pad(v, (1, 1, 1), specs(f))
-                    for f, v in (("vx", vx), ("vy", vy), ("vz", vz))]
-            vx_s, vy_s, vz_s = ops.update_velocity(*pads, **vel_params, **kw)
-
-        vx_s, vy_s, vz_s = vx_s * mvx, vy_s * mvy, vz_s * mvz
+                packed = jnp.stack([vx, vy, vz])
+                out = stencil_step_overlap(
+                    packed, (0, 1, 1, 1), specs=None, kernel=upd_packed,
+                    pad_fn=pad_packed)
+                vx_s, vy_s, vz_s = out[0], out[1], out[2]
+            else:
+                pads = [exchange_pad(v, (1, 1, 1), specs(f))
+                        for f, v in (("vx", vx), ("vy", vy), ("vz", vz))]
+                vx_s, vy_s, vz_s = ops.update_velocity(*pads, **vel_params,
+                                                       **kw)
+            vx_s, vy_s, vz_s = vx_s * mvx, vy_s * mvy, vz_s * mvz
 
         # -- 2. divergence rhs
-        pads = [exchange_pad(v, ((1, 0),) * 3, specs(f))
-                for f, v in (("vx", vx_s), ("vy", vy_s), ("vz", vz_s))]
-        rhs = ops.divergence(*pads, h=h, **kw) / dt
+        with jax.named_scope("divergence"):
+            pads = [exchange_pad(v, ((1, 0),) * 3, specs(f))
+                    for f, v in (("vx", vx_s), ("vy", vy_s), ("vz", vz_s))]
+            rhs = ops.divergence(*pads, h=h, **kw) / dt
 
         # -- 3. pressure Poisson (warm start from previous p)
         p_specs = specs("p")
@@ -285,14 +293,17 @@ class NavierStokes3D:
             return jacobi_fused_ref(pp, rr, h=h, omega=c.jacobi_omega, sweeps=k)
 
         iters = max(c.jacobi_iters // max(k, 1), 1)
-        p_new = lax.fori_loop(0, iters, jacobi_body, p)
-        p_new = p_new - self._global_mean(p_new)  # pin the Neumann null space
+        with jax.named_scope("jacobi"):
+            p_new = lax.fori_loop(0, iters, jacobi_body, p)
+            # pin the Neumann null space
+            p_new = p_new - self._global_mean(p_new)
 
         # -- 4. projection
-        pp = exchange_pad(p_new, ((0, 1),) * 3, p_specs)
-        vx_n, vy_n, vz_n = ops.project_velocity(vx_s, vy_s, vz_s, pp,
-                                                dt=dt, h=h, **kw)
-        vx_n, vy_n, vz_n = vx_n * mvx, vy_n * mvy, vz_n * mvz
+        with jax.named_scope("project"):
+            pp = exchange_pad(p_new, ((0, 1),) * 3, p_specs)
+            vx_n, vy_n, vz_n = ops.project_velocity(vx_s, vy_s, vz_s, pp,
+                                                    dt=dt, h=h, **kw)
+            vx_n, vy_n, vz_n = vx_n * mvx, vy_n * mvy, vz_n * mvz
 
         return dict(state, vx=vx_n, vy=vy_n, vz=vz_n, p=p_new)
 

@@ -146,11 +146,13 @@ def exchange_pad(
     stencils.  Must run inside ``shard_map`` when any spec names a mesh axis.
     Corner ghosts are produced correctly because later axes exchange the
     already-padded earlier axes (the standard two-phase corner trick).
+    Its ops carry the ``exchange_pad`` scope in their metadata.
     """
     if len(widths) != len(specs):
         raise ValueError("widths and specs length mismatch")
-    for w, spec in zip(widths, specs):
-        u = _pad_axis(u, w, spec)
+    with jax.named_scope("exchange_pad"):
+        for w, spec in zip(widths, specs):
+            u = _pad_axis(u, w, spec)
     return u
 
 

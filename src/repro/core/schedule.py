@@ -101,13 +101,13 @@ class Schedule:
         """Compose the bin's routines (topologically sorted) into one fn.
 
         With an *enabled* :class:`repro.obs.Telemetry`, the composed
-        runner is the Cactus-instrumented one: the bin and each routine
-        get hierarchical wall-clock timer sections (fenced with
-        ``block_until_ready`` so async dispatch is charged to the routine
-        that issued it) plus ``jax.named_scope`` annotations so bins show
-        up in XLA profiles.  Telemetry ``None``/disabled returns exactly
-        the uninstrumented composition — the zero-telemetry path has no
-        fences, no clocks, and identical numerics.
+        runner is the Cactus-instrumented one: the bin is a
+        ``schedule.<BIN>`` span and timer section, and each routine a
+        nested timer section (fenced with ``block_until_ready`` so async
+        dispatch is charged to the routine that issued it).  Telemetry
+        ``None``/disabled returns exactly the uninstrumented composition
+        — the zero-telemetry path has no fences, no clocks, and identical
+        numerics.
         """
         entries = self._sorted(bin)
 
@@ -130,8 +130,7 @@ class Schedule:
         def run(state: State) -> State:
             with tel.section(f"schedule.{bname}"):
                 for e in entries:
-                    with tel.section(e.name), \
-                            tel.named_scope(f"{bname}.{e.name}"):
+                    with tel.timers.section(e.name):
                         state = e.fn(state)
                         if per_entry_fence:
                             tel.fence(state)

@@ -18,11 +18,18 @@ one handle:
   streamed as JSON-lines and exportable to Chrome trace-event format
   (Perfetto-loadable).
 
+Spans are on whatever the telemetry: every ``section`` is a
+``jax.profiler.TraceAnnotation`` (:func:`span`) on the profiler's clock,
+its keyword counts recorded as the event's stats, and it never waits on
+the device.  A profile of any run therefore shows the farm's host phases
+and slot I/O by the names in :data:`SPANS`; with no profiler running a
+span costs under a microsecond.
+
 The contract that makes it safe to thread everywhere: **telemetry off is
 bitwise-invisible**.  A disabled :class:`Telemetry` (the :data:`NULL`
-singleton) makes every hook a no-op — no timers, no
-``jax.block_until_ready`` fences, no named scopes, no events — so the
-default execution path is byte-for-byte the pre-telemetry one.  Enable it
+singleton) makes every other hook a no-op — no timers, no
+``jax.block_until_ready`` fences, no events — so the default execution
+path computes byte-for-byte what the pre-telemetry one did.  Enable it
 per-runtime (``repro.api.runtime(..., telemetry=True)``) or standalone::
 
     tel = repro.obs.telemetry(trace_path="events.jsonl")
@@ -53,30 +60,50 @@ __all__ = [
     "HealthMonitor", "Histogram", "NULL", "Registry", "Telemetry",
     "TelemetryConfig", "TimerNode", "TimerTree", "TraceLog", "host_info",
     "load_bench", "load_flight_record", "make_bench_doc",
-    "render_dashboard", "report", "resolve", "resolve_health",
-    "series_key", "telemetry", "validate_bench", "validate_chrome_trace",
-    "write_bench",
+    "SPANS", "render_dashboard", "report", "resolve", "resolve_health",
+    "series_key", "span", "telemetry", "validate_bench",
+    "validate_chrome_trace", "write_bench",
 ]
 
 _NULL_CM = contextlib.nullcontext()
+
+# Every span the program emits through :meth:`Telemetry.section` or
+# :func:`span`.  The ``farm.*`` / ``ensemble.*`` / ``service.*`` /
+# ``runtime.*`` names are emitted whatever the telemetry; ``schedule.<BIN>``
+# only by a bin composed under enabled telemetry.
+SPANS = (
+    "service.run", "service.result_snapshot", "service.evict_spill",
+    "service.readmit_restore",
+    "farm.admit", "farm.step_chunk", "farm.harvest", "farm.health_drain",
+    "farm.check_steady", "farm.quarantine", "farm.evict",
+    "ensemble.write_slot", "ensemble.read_slot",
+    "runtime.step",
+    "schedule.INITIAL", "schedule.PRESTEP", "schedule.EVOL",
+    "schedule.POSTSTEP", "schedule.ANALYSIS",
+)
+
+
+def span(name: str, **counts):
+    """A host span on the profiler's clock: ``jax.profiler.TraceAnnotation``
+    with ``counts`` as the event's stats.  It never syncs with the device,
+    and costs under a microsecond when no profiler is running."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name, **counts)
 
 
 @dataclasses.dataclass(frozen=True)
 class TelemetryConfig:
     """How much to observe, and where the byproducts land.
 
-    ``named_scopes`` additionally wraps instrumented regions in
-    ``jax.named_scope`` + ``jax.profiler.TraceAnnotation`` so schedule
-    bins show up in XLA/perfetto device profiles.  The heartbeat fields
-    drive the service watchdog: a liveness file touched every
-    ``heartbeat_interval_s`` (for an external orchestrator), and a stall
-    recorded whenever consecutive beats are further apart than
-    ``heartbeat_deadline_s``.
+    The heartbeat fields drive the service watchdog: a liveness file
+    touched every ``heartbeat_interval_s`` (for an external
+    orchestrator), and a stall recorded whenever consecutive beats are
+    further apart than ``heartbeat_deadline_s``.
     """
 
     enabled: bool = True
     trace_path: str | None = None        # stream events as JSON-lines
-    named_scopes: bool = True            # annotate XLA profiles
     heartbeat_path: str | None = None    # liveness file (ft.watchdog)
     heartbeat_interval_s: float = 5.0
     heartbeat_deadline_s: float = 60.0
@@ -96,21 +123,11 @@ class Telemetry:
         _CURRENT = self
 
     # -- hooks (every one a no-op on NULL) ------------------------------------
-    def section(self, name: str):
-        """Timer context manager for a nested wall-clock section."""
-        return self.timers.section(name)
-
-    def named_scope(self, name: str):
-        """XLA-profile annotation: ``jax.named_scope`` (trace-time op
-        metadata) + ``jax.profiler.TraceAnnotation`` (host timeline)."""
-        if not self.config.named_scopes:
-            return _NULL_CM
-        import jax
-
-        ctx = contextlib.ExitStack()
-        ctx.enter_context(jax.named_scope(name))
-        ctx.enter_context(jax.profiler.TraceAnnotation(name))
-        return ctx
+    @contextlib.contextmanager
+    def section(self, name: str, **counts):
+        """A :func:`span` that also feeds the nested wall-clock timer."""
+        with span(name, **counts), self.timers.section(name):
+            yield
 
     def fence(self, x):
         """``jax.block_until_ready`` so a section's clock covers the
@@ -162,11 +179,8 @@ class _NullTelemetry(Telemetry):
         self.timers = _NullTimerTree()
         self.trace = _NullTraceLog()
 
-    def section(self, name):
-        return _NULL_CM
-
-    def named_scope(self, name):
-        return _NULL_CM
+    def section(self, name, **counts):
+        return span(name, **counts)
 
     def fence(self, x):
         return x

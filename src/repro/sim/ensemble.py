@@ -296,9 +296,9 @@ class EnsembleExecutor:
         place = ((lambda v: v if isinstance(v, jax.Array)
                   else jax.device_put(np.asarray(v), sh))
                  if sh is not None else jnp.asarray)
-        src = self._fresh if state is None else {
-            k: place(v) for k, v in state.items()}
         with self.tel.section("ensemble.write_slot"):
+            src = self._fresh if state is None else {
+                k: place(v) for k, v in state.items()}
             self.state = jax.tree_util.tree_map(
                 lambda full, one: lax.dynamic_update_index_in_dim(
                     full, one.astype(full.dtype), slot, 0),
@@ -313,8 +313,12 @@ class EnsembleExecutor:
         self._params_dev = None
 
     def read_slot(self, slot: int) -> dict:
-        """Host copy of one simulation's fields."""
-        with self.tel.section("ensemble.read_slot"):
+        """Host copy of one simulation's fields: one device->host copy per
+        field, counted in the span's ``transfers`` and ``bytes`` stats."""
+        nbytes = sum(v.size // v.shape[0] * v.dtype.itemsize
+                     for v in self.state.values())
+        with self.tel.section("ensemble.read_slot",
+                              transfers=len(self.state), bytes=nbytes):
             return {k: np.asarray(v[slot]) for k, v in self.state.items()}
 
     def clear_slot(self, slot: int):

@@ -172,11 +172,13 @@ class _SlotEntry:
 class SimulationFarm:
     """Queue + slots + termination around one compiled ensemble step.
 
-    ``telemetry`` (any :func:`repro.obs.resolve` spec) instruments the
-    farm: hierarchical timers around the admit / step-chunk / harvest
-    phases, ``farm.*`` / ``sim.*`` metrics, and per-sim lifecycle trace
-    events.  Disabled (the default) every hook is a no-op — results are
-    bitwise those of an uninstrumented farm, with no extra device syncs.
+    The host phases are profiler spans (``farm.admit``,
+    ``farm.step_chunk``, ``farm.harvest``, ... in ``repro.obs.SPANS``)
+    whatever the telemetry.  ``telemetry`` (any :func:`repro.obs.resolve`
+    spec) adds hierarchical timers over those phases, ``farm.*`` /
+    ``sim.*`` metrics, and per-sim lifecycle trace events.  Disabled (the
+    default) those hooks are no-ops — results are bitwise those of an
+    uninstrumented farm, with no extra device syncs.
     ``farm_id`` tags this farm's trace events when several farms share
     one telemetry handle (the Runtime's one-service-per-signature case).
 
@@ -467,40 +469,40 @@ class SimulationFarm:
     def _check_steady(self, resid=None):
         if self.device_steps % self.check_steady_every:
             return
-        if resid is not None:
-            for slot, entry in list(self.table.occupied()):
-                tol = entry.req.residual_tol
-                if tol is not None and float(resid[slot]) <= tol:
-                    self._finish(slot, entry, "residual")
-        watched = [(s, e) for s, e in self.table.occupied()
-                   if e.req.steady_tol is not None]
-        if not watched:
-            return
-        ke = self.exec.kinetic_energy()
-        for slot, entry in watched:
-            k = float(ke[slot])
-            prev = entry.ke_prev
-            entry.ke_prev = k
-            if prev is not None and abs(k - prev) <= entry.req.steady_tol * max(
-                    abs(k), 1e-12):
-                self._finish(slot, entry, "steady")
+        with self.tel.section("farm.check_steady"):
+            if resid is not None:
+                for slot, entry in list(self.table.occupied()):
+                    tol = entry.req.residual_tol
+                    if tol is not None and float(resid[slot]) <= tol:
+                        self._finish(slot, entry, "residual")
+            watched = [(s, e) for s, e in self.table.occupied()
+                       if e.req.steady_tol is not None]
+            if not watched:
+                return
+            ke = self.exec.kinetic_energy()
+            for slot, entry in watched:
+                k = float(ke[slot])
+                prev = entry.ke_prev
+                entry.ke_prev = k
+                if prev is not None and abs(k - prev) <= \
+                        entry.req.steady_tol * max(abs(k), 1e-12):
+                    self._finish(slot, entry, "steady")
 
     def _finish(self, slot: int, entry: _SlotEntry, reason: str):
         req = entry.req
         with self.tel.section("farm.harvest"):
-            state = self.exec.read_slot(slot)
-            self.tel.fence(state)
-        self.results[req.sid] = SimResult(
-            sid=req.sid, tag=req.tag, steps_done=entry.steps_done,
-            terminated=reason, state=state, config=req.config)
-        self._live.discard(req.sid)
-        self.table.release(slot)
-        self.exec.clear_slot(slot)
-        if self.monitor is not None:
-            self.monitor.release(req.sid)
-        self._resolved(req, entry.steps_done, reason)
-        if self.on_transition is not None:
-            self.on_transition("done", req, self.results[req.sid])
+            self.results[req.sid] = SimResult(
+                sid=req.sid, tag=req.tag, steps_done=entry.steps_done,
+                terminated=reason, state=self.exec.read_slot(slot),
+                config=req.config)
+            self._live.discard(req.sid)
+            self.table.release(slot)
+            self.exec.clear_slot(slot)
+            if self.monitor is not None:
+                self.monitor.release(req.sid)
+            self._resolved(req, entry.steps_done, reason)
+            if self.on_transition is not None:
+                self.on_transition("done", req, self.results[req.sid])
 
     def _fail(self, slot: int, entry: _SlotEntry, exc: BaseException):
         """Record a per-sim failure as a harvestable result and free the
@@ -578,7 +580,6 @@ class SimulationFarm:
             if entry.req.sid == sid:
                 with self.tel.section("farm.evict"):
                     state = self.exec.read_slot(slot)
-                    self.tel.fence(state)
                 self._live.discard(sid)
                 self.table.release(slot)
                 self.exec.clear_slot(slot)

@@ -250,7 +250,8 @@ class SimulationService:
     # -- driving --------------------------------------------------------------
     def run(self, device_steps: int) -> int:
         """Advance the farm up to ``device_steps``; returns steps taken."""
-        return self.farm.run(device_steps)
+        with self.tel.section("service.run"):
+            return self.farm.run(device_steps)
 
     def result(self, sid: int, block: bool = True,
                max_device_steps: int = 100_000) -> SimResult:

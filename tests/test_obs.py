@@ -1,8 +1,11 @@
 """repro.obs: timer-nesting invariants, metrics round-trips, per-sim trace
 ordering (failed sims included), Chrome-trace schema, the bench-document
-schema, watchdog wiring, compile-cache scoping — and the frozen contract
-that telemetry off is bitwise-invisible."""
+schema, watchdog wiring, compile-cache scoping, the program's profiler
+spans and stage scopes — and the frozen contract that telemetry off is
+bitwise-invisible."""
+import glob
 import json
+import re
 import threading
 
 import jax
@@ -776,8 +779,114 @@ class TestResolve:
         assert obs.resolve(True).enabled
         tel = obs.telemetry()
         assert obs.resolve(tel) is tel
-        assert obs.resolve({"named_scopes": False}).config.named_scopes \
-            is False
+        assert obs.resolve({"heartbeat_interval_s": 2.0}).config \
+            .heartbeat_interval_s == 2.0
+        # the disabled handle's section is still a profiler span
+        assert isinstance(obs.NULL.section("farm.admit"),
+                          jax.profiler.TraceAnnotation)
         assert obs.resolve(obs.TelemetryConfig(enabled=False)) is obs.NULL
         with pytest.raises(TypeError):
             obs.resolve(42)
+
+
+# ---------------------------------------------------------------------------
+# profiler spans and stage scopes
+# ---------------------------------------------------------------------------
+STAGES = ("update_velocity", "divergence", "jacobi", "project",
+          "exchange_pad")
+
+
+def _farm_run(telemetry):
+    """Two waves through a 2-slot farm via ``SimulationService.run``, and
+    two serial steps through ``Runtime.prepare``."""
+    rt = api.runtime(n=N, n_slots=2, telemetry=telemetry, **KW)
+    for re_ in (70.0, 150.0, 300.0):
+        rt.submit("cavity", re=re_, steps=4)
+    (svc,) = rt.services()
+    while svc.farm.table.n_queued or svc.farm.table.n_active:
+        svc.run(4)
+    pr = rt.prepare("cavity")
+    jax.block_until_ready(pr.step(pr.step(pr.state)))
+    return rt, svc
+
+
+class TestSpans:
+    @pytest.mark.parametrize("telemetry", [False, True])
+    def test_every_emitted_span_is_published(self, monkeypatch, telemetry):
+        seen = []
+        real = obs.span
+
+        def record(name, **counts):
+            seen.append((name, counts))
+            return real(name, **counts)
+
+        monkeypatch.setattr(obs, "span", record)
+        _, svc = _farm_run(telemetry)
+        names = {n for n, _ in seen}
+        assert names <= set(obs.SPANS), names - set(obs.SPANS)
+        assert {"service.run", "farm.admit", "farm.step_chunk",
+                "farm.harvest", "ensemble.write_slot", "ensemble.read_slot",
+                "runtime.step"} <= names
+        assert all(n.startswith(("service.", "farm.", "ensemble.",
+                                 "runtime.", "schedule."))
+                   for n in obs.SPANS)
+        fields = svc.farm.exec.state
+        want = {"transfers": len(fields),
+                "bytes": sum(v[0].size * v.dtype.itemsize
+                             for v in fields.values())}
+        reads = [c for n, c in seen if n == "ensemble.read_slot"]
+        assert len(reads) == 3 and all(c == want for c in reads)
+        assert want["transfers"] == 7   # vx, vy, vz, p and three masks
+
+    def test_span_carries_its_stats_into_the_trace(self, tmp_path):
+        jax.profiler.start_trace(str(tmp_path))
+        with obs.NULL.section("ensemble.read_slot", transfers=7, bytes=96):
+            with obs.telemetry().section("farm.harvest", members=3):
+                pass
+        jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        got = {e.name: dict(e.stats)
+               for plane in jax.profiler.ProfileData.from_file(path).planes
+               for line in plane.lines for e in line.events
+               if e.name in ("ensemble.read_slot", "farm.harvest")}
+        assert got == {"ensemble.read_slot": {"transfers": 7, "bytes": 96},
+                       "farm.harvest": {"members": 3}}
+
+    def test_telemetry_off_farm_never_waits_from_obs(self, monkeypatch):
+        import sys
+
+        callers = []
+        real = jax.block_until_ready
+
+        def counting(x):
+            callers.append(sys._getframe(1).f_globals["__name__"])
+            return real(x)
+
+        def from_obs():
+            return sum(name.startswith("repro.obs") for name in callers)
+
+        monkeypatch.setattr(jax, "block_until_ready", counting)
+        _farm_run(False)
+        assert from_obs() == 0
+        _farm_run(True)
+        assert from_obs() > 0      # the counter sees the enabled fences
+
+    @pytest.mark.parametrize("where", ["serial", "farm"])
+    def test_step_ops_carry_the_stage_scopes(self, where):
+        rt = api.runtime(n=N, n_slots=2, **KW)
+        if where == "serial":
+            pr = rt.prepare("cavity")
+            lowered = jax.jit(pr.step).lower(pr.state)
+        else:
+            rt.submit("cavity", re=100.0, steps=1)
+            (svc,) = rt.services()
+            ex = svc.farm.exec
+            lowered = ex._run_k.lower(*ex.step_args(1))
+        paths = set(re.findall(r'op_name="([^"]*)"',
+                               lowered.compile().as_text()))
+        for stage in STAGES:
+            pat = re.compile(rf"(^|[/(]){stage}([)/]|$)")
+            assert any(pat.search(p) for p in paths), stage
+        if where == "farm":
+            assert any("vmap(jacobi)" in p for p in paths)

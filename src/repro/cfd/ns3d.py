@@ -83,10 +83,16 @@ def params_from_config(c: CFDConfig) -> dict:
     these through the step, so a farm slot is bit-identical to a serial run
     of the same configuration.
     """
+    return {k: jnp.float32(v) for k, v in host_params(c).items()}
+
+
+def host_params(c: CFDConfig) -> dict:
+    """:func:`params_from_config`'s values as host ``np.float32`` scalars:
+    what the farm installs at admission, with no device round trip."""
     fx, fy, fz = c.forcing
     vals = dict(nu=c.nu, dt=c.dt, lid_velocity=c.lid_velocity,
                 fx=fx, fy=fy, fz=fz)
-    return {k: jnp.float32(vals[k]) for k in PARAM_KEYS}
+    return {k: np.float32(vals[k]) for k in PARAM_KEYS}
 
 
 # Cases whose domain is fully periodic (no wall BCs, no wall masks).

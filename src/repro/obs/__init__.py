@@ -77,6 +77,7 @@ SPANS = (
     "farm.admit", "farm.step_chunk", "farm.harvest", "farm.health_drain",
     "farm.check_steady", "farm.quarantine", "farm.evict",
     "ensemble.write_slot", "ensemble.read_slot",
+    "ensemble.write_slots", "ensemble.read_slots",
     "runtime.step",
     "schedule.INITIAL", "schedule.PRESTEP", "schedule.EVOL",
     "schedule.POSTSTEP", "schedule.ANALYSIS",

@@ -176,6 +176,44 @@ def make_ensemble_step(solver: NavierStokes3D, *, mesh=None,
     return jax.jit(fn)
 
 
+# -- slot I/O programs -------------------------------------------------------
+def _lengths(n_slots: int) -> list:
+    """The padded lengths of a slot I/O round: the powers of two below
+    ``n_slots``, then ``n_slots``.  A round pads to less than twice its
+    members, and one compile per length serves every round size."""
+    return [1 << i for i in range((n_slots - 1).bit_length())] + [n_slots]
+
+
+def _padded(k: int, n_slots: int) -> int:
+    """The length of :func:`_lengths` a round of ``k`` slots pads to."""
+    return min(1 << (k - 1).bit_length(), n_slots)
+
+
+def _frozen(v) -> np.ndarray:
+    """A read-only host copy of ``v``."""
+    out = np.array(v)
+    out.flags.writeable = False
+    return out
+
+
+def _write_fresh(state, fresh, mask):
+    """``state`` with one slot's ``fresh`` fields wherever the
+    ``(n_slots,)`` ``mask`` is set."""
+    return {k: jnp.where(mask.reshape((-1,) + (1,) * (v.ndim - 1)),
+                         fresh[k].astype(v.dtype)[None], v)
+            for k, v in state.items()}
+
+
+def _write_rows(state, idx, rows):
+    """``state`` with row ``i`` of each field of ``rows`` written to slot
+    ``idx[i]``; an index past the batch is padding and is dropped."""
+    return {k: v.at[idx].set(rows[k], mode="drop") for k, v in state.items()}
+
+
+# the harvest's gather: the fields of the slots ``idx`` names, in order
+_GATHER = jax.jit(lambda fields, idx: {k: v[idx] for k, v in fields.items()})
+
+
 class EnsembleExecutor:
     """Slot-stacked state + the single jitted step that advances it.
 
@@ -218,6 +256,26 @@ class EnsembleExecutor:
                     if decomp else slot_spec(mesh, n_slots, axis=slot_axis))
             self.state = jax.device_put(self.state,
                                         NamedSharding(mesh, spec))
+            # stacked host rows of a readmission round: grid axes as one
+            # slot's fields, the round axis replicated
+            self._row_sharding = NamedSharding(
+                mesh, P(None, *self.solver.field_pspec) if decomp else P())
+        else:
+            # committed like the step's output, so the slot I/O programs
+            # compiled before the first step serve every later round
+            self._row_sharding = jax.sharding.SingleDeviceSharding(
+                next(iter(fresh["vx"].devices())))
+            self.state = jax.device_put(self.state, self._row_sharding)
+        # the fields the step writes; the rest (the wall masks) pass
+        # through it unchanged, so harvests take them from host copies
+        # made when each slot was written
+        self._dynamic = tuple(k for k in self.state
+                              if k in self.solver.FIELDS)
+        self._static_fresh = {k: _frozen(v) for k, v in fresh.items()
+                              if k not in self._dynamic}
+        self._static = [self._static_fresh] * n_slots
+        self._fresh_write = None  # compiled at the first slot I/O
+        self._rows_write = None   # compiled at the first host state
         # device-side health ring: (slots, K, N_DIAG), shift-append (row
         # K-1 is the newest frame).  Column 0 is the device-step stamp:
         # -1 = blank sentinel on device; `read_health` overwrites it from
@@ -280,46 +338,147 @@ class EnsembleExecutor:
             return None
         return NamedSharding(self.mesh, self.solver.field_pspec)
 
-    def write_slot(self, slot: int, params: dict, state: dict | None = None):
-        """Admit a simulation: install its parameters and (re)set its fields.
+    def check_state(self, state: dict) -> dict:
+        """``state`` as host arrays in the batch's dtypes, checked against
+        one slot's fields: the same names and shapes, or ValueError.  The
+        farm checks each readmission on its own before batching a round,
+        so a mis-shaped one fails alone."""
+        if set(state) != set(self.state):
+            raise ValueError(
+                f"state fields {sorted(state)} do not match the farm's "
+                f"{sorted(self.state)}")
+        out = {}
+        for k, full in self.state.items():
+            v = np.asarray(state[k], dtype=full.dtype)
+            if v.shape != full.shape[1:]:
+                raise ValueError(f"state field {k!r} has shape {v.shape}, "
+                                 f"a slot holds {full.shape[1:]}")
+            out[k] = v
+        return out
 
-        ``state=None`` writes the case's fresh initial state (new run);
-        passing a host state dict readmits an evicted simulation — on a
-        decomposed farm the host fields are scattered to the slot's shard
-        layout before entering the resident batch.
+    def _io_programs(self):
+        """Compile the fresh-admission update and the harvest gather of
+        every round length at the first slot I/O call (a warm-up's), so no
+        later round compiles."""
+        if self._fresh_write is not None:
+            return
+        out = {k: v.sharding for k, v in self.state.items()}
+        fresh = jax.jit(_write_fresh, donate_argnums=0, out_shardings=out)
+        fresh.lower(self.state, self._fresh,
+                    np.zeros(self.n_slots, bool)).compile()
+        dynamic = {k: self.state[k] for k in self._dynamic}
+        for b in _lengths(self.n_slots):
+            _GATHER.lower(dynamic, np.zeros(b, np.int32)).compile()
+        self._fresh_write = fresh
+
+    def _rows_program(self):
+        """The readmission scatter, compiled for every padded round length
+        at the first round that carries a host state."""
+        if self._rows_write is None:
+            out = {k: v.sharding for k, v in self.state.items()}
+            rows = jax.jit(_write_rows, donate_argnums=0, out_shardings=out)
+            for b in _lengths(self.n_slots):
+                rows.lower(self.state, np.zeros(b, np.int32), {
+                    k: jax.ShapeDtypeStruct((b,) + v.shape[1:], v.dtype,
+                                            sharding=self._row_sharding)
+                    for k, v in self.state.items()}).compile()
+            self._rows_write = rows
+        return self._rows_write
+
+    def write_slots(self, admits: list):
+        """Admit a round of simulations: install their parameters and
+        (re)set their fields, in one update of the resident batch.
+
+        ``admits`` holds ``(slot, params, state)`` triples.  ``params``
+        are the per-slot scalars, read on the host.  ``state=None`` writes
+        the case's fresh initial fields (a new run): one donated ``where``
+        over an ``(n_slots,)`` mask writes every fresh slot of the round.
+        A host state dict readmits an evicted simulation: the round's host
+        states are stacked per field, placed with one host->device copy
+        per field (on a decomposed farm straight to the grid shards) and
+        scattered in one more dispatch, the round padded to a length of
+        :func:`_lengths` whose padding is dropped.  The span
+        ``ensemble.write_slots`` carries ``members``, ``transfers`` (the
+        host->device field copies) and their ``bytes``.
         """
-        sh = self.slot_sharding()
-        # host -> shards directly (device_put scatters a numpy array
-        # per-shard); staging through jnp.asarray would first materialize
-        # the FULL field on the default device — the one thing a
-        # decomposed slot must never need
-        place = ((lambda v: v if isinstance(v, jax.Array)
-                  else jax.device_put(np.asarray(v), sh))
-                 if sh is not None else jnp.asarray)
-        with self.tel.section("ensemble.write_slot"):
-            src = self._fresh if state is None else {
-                k: place(v) for k, v in state.items()}
-            self.state = jax.tree_util.tree_map(
-                lambda full, one: lax.dynamic_update_index_in_dim(
-                    full, one.astype(full.dtype), slot, 0),
-                self.state, dict(src))
+        fresh = [slot for slot, _, state in admits if state is None]
+        hosted = [(slot, self.check_state(state))
+                  for slot, _, state in admits if state is not None]
+        rows = idx = None
+        if hosted:
+            b = _padded(len(hosted), self.n_slots)
+            pad = [hosted[-1][1]] * (b - len(hosted))
+            idx = np.full(b, self.n_slots, np.int32)
+            idx[:len(hosted)] = [slot for slot, _ in hosted]
+            states = [state for _, state in hosted] + pad
+            rows = {k: np.stack([s[k] for s in states]) for k in self.state}
+        with self.tel.section(
+                "ensemble.write_slots", members=len(admits),
+                transfers=len(rows) if rows else 0,
+                bytes=sum(v.nbytes for v in rows.values()) if rows else 0):
+            self._io_programs()
+            if fresh:
+                mask = np.zeros(self.n_slots, bool)
+                mask[fresh] = True
+                self.state = self._fresh_write(self.state, self._fresh,
+                                               mask)
+            if rows:
+                program = self._rows_program()
+                self.state = program(
+                    self.state, idx, jax.device_put(rows, self._row_sharding))
             # the health ring is deliberately NOT reset here: its step
             # column is the executor's monotonic step counter, so the
             # monitor filters a previous occupant's rows by admit-time
             # device step — admission stays a single state update
             self.tel.fence(self.state)
-        for k in PARAM_KEYS:
-            self.params[k][slot] = np.float32(params[k])
+        for slot in fresh:
+            self._static[slot] = self._static_fresh
+        for slot, state in hosted:
+            self._static[slot] = {k: _frozen(state[k])
+                                  for k in self._static_fresh}
+        for slot, params, _ in admits:
+            for k in PARAM_KEYS:
+                self.params[k][slot] = np.float32(params[k])
         self._params_dev = None
 
+    def write_slot(self, slot: int, params: dict, state: dict | None = None):
+        """Admit one simulation: :meth:`write_slots` with one member."""
+        with self.tel.section("ensemble.write_slot"):
+            self.write_slots([(slot, params, state)])
+
+    def read_slots(self, slots: list) -> list:
+        """Host copies of a round's simulations, in the order of ``slots``:
+        one device->host copy per field the step writes, for all of them.
+
+        A gather of a padded length (:func:`_lengths`) picks the round's
+        slots, so a round of one moves one member's fields and a round of
+        most of the batch the whole batch's.  The masks, which the step
+        never writes, come from the host copies made at admission.  The
+        span ``ensemble.read_slots`` carries ``members``, ``transfers``
+        and their ``bytes``.
+        """
+        if not slots:
+            return []
+        self._io_programs()
+        b = _padded(len(slots), self.n_slots)
+        idx = np.full(b, slots[0], np.int32)
+        idx[:len(slots)] = slots
+        fields = {k: self.state[k] for k in self._dynamic}
+        nbytes = sum(b * v.nbytes // v.shape[0] for v in fields.values())
+        with self.tel.section("ensemble.read_slots", members=len(slots),
+                              transfers=len(fields), bytes=nbytes):
+            got = _GATHER(fields, idx)
+            for v in got.values():
+                v.copy_to_host_async()
+            host = {k: np.asarray(v) for k, v in got.items()}
+        return [{k: host[k][i] if k in host else self._static[slot][k]
+                 for k in self.state} for i, slot in enumerate(slots)]
+
     def read_slot(self, slot: int) -> dict:
-        """Host copy of one simulation's fields: one device->host copy per
-        field, counted in the span's ``transfers`` and ``bytes`` stats."""
-        nbytes = sum(v.size // v.shape[0] * v.dtype.itemsize
-                     for v in self.state.values())
-        with self.tel.section("ensemble.read_slot",
-                              transfers=len(self.state), bytes=nbytes):
-            return {k: np.asarray(v[slot]) for k, v in self.state.items()}
+        """Host copy of one simulation's fields: :meth:`read_slots` with
+        one member."""
+        with self.tel.section("ensemble.read_slot"):
+            return self.read_slots([slot])[0]
 
     def clear_slot(self, slot: int):
         """Park a freed slot on benign parameters (finite garbage compute)."""

@@ -24,7 +24,7 @@ from typing import Any
 import jax
 
 from repro import obs
-from repro.cfd.ns3d import CFDConfig, NavierStokes3D, params_from_config
+from repro.cfd.ns3d import CFDConfig, NavierStokes3D, host_params
 from repro.serve.slots import SlotTable
 from repro.sim.ensemble import (
     EnsembleExecutor, make_ensemble_step, plan_decomposition,
@@ -182,6 +182,11 @@ class SimulationFarm:
     ``farm_id`` tags this farm's trace events when several farms share
     one telemetry handle (the Runtime's one-service-per-signature case).
 
+    Slot I/O moves by host round, not by member: a round's admissions are
+    one ``EnsembleExecutor.write_slots`` call and the sims that finish at
+    one boundary one ``read_slots`` call, so a wave of many members costs
+    the device about what one member does.
+
     ``health`` (any :func:`repro.obs.health.resolve_health` spec) turns
     on in-situ health monitoring: the compiled step accumulates per-sim
     physics diagnostics into a device ring buffer, drained at the same
@@ -283,42 +288,69 @@ class SimulationFarm:
         return req.sid
 
     def _admit(self):
+        """Admit queued work into every free slot: each round's members
+        are written by one ``write_slots`` call, and a steps=0 member is
+        harvested at once, which frees its slot for the next round."""
         with self.tel.section("farm.admit"):
             while True:
-                admitted = self.table.admit_next()
-                if admitted is None:
+                admits = self._collect_admits()
+                if not admits:
                     break
-                slot, req = admitted
-                # replace the queued request with live bookkeeping
-                entry = _SlotEntry(req)
-                self.table.replace(slot, entry)
-                self.tel.trace.emit("admit", sid=req.sid, farm=self.farm_id,
-                                    slot=slot, step0=req.step0, tag=req.tag)
-                if self.monitor is not None:
-                    # rows stamped <= the current device step belong to
-                    # the slot's previous occupant
-                    self.monitor.admit(req.sid, slot, tag=req.tag,
-                                       last_step=self.device_steps - 1)
                 try:
-                    self.exec.write_slot(slot,
-                                         params_from_config(req.config),
-                                         state=req.init_state)
+                    self.exec.write_slots([(slot, params, state) for
+                                           slot, _, params, state in admits])
                 except Exception as e:
-                    # a request whose admission raises (bad readmission
-                    # state, mis-shaped fields, ...) must fail alone —
-                    # recorded as a per-sim failed result — instead of
-                    # poisoning the farm or leaving its sid queued/running
-                    # forever
-                    self._fail(slot, entry, e)
+                    # the round's one dispatch failed: no member of it
+                    # holds its slot
+                    for slot, entry, _, _ in admits:
+                        self._fail(slot, entry, e)
                     continue
-                if self.on_transition is not None:
-                    self.on_transition("running", req, None)
-                if entry.steps_done >= req.steps:
-                    # already at (or past) its target: harvest without
-                    # stepping, so a steps=0 request never advances the
-                    # batch
-                    self._finish(slot, entry, "steps")
+                done = []
+                for slot, entry, _, _ in admits:
+                    if self.on_transition is not None:
+                        self.on_transition("running", entry.req, None)
+                    if entry.steps_done >= entry.req.steps:
+                        # already at (or past) its target: harvest without
+                        # stepping, so a steps=0 request never advances
+                        # the batch
+                        done.append((slot, entry, "steps"))
+                if not done:
+                    break
+                self._harvest(done)
             self._gauge_load()
+
+    def _collect_admits(self) -> list:
+        """Take queued requests into free slots: ``(slot, entry, host
+        params, host state or None)`` for each.  A request whose scalars or
+        readmission state do not fit a slot fails alone, recorded as a
+        per-sim failed result, and its slot takes the next request."""
+        admits = []
+        while True:
+            admitted = self.table.admit_next()
+            if admitted is None:
+                return admits
+            slot, req = admitted
+            # replace the queued request with live bookkeeping
+            entry = _SlotEntry(req)
+            self.table.replace(slot, entry)
+            self.tel.trace.emit("admit", sid=req.sid, farm=self.farm_id,
+                                slot=slot, step0=req.step0, tag=req.tag)
+            if self.monitor is not None:
+                # rows stamped <= the current device step belong to the
+                # slot's previous occupant
+                self.monitor.admit(req.sid, slot, tag=req.tag,
+                                   last_step=self.device_steps - 1)
+            try:
+                params = host_params(req.config)
+                state = (None if req.init_state is None
+                         else self.exec.check_state(req.init_state))
+            except Exception as e:
+                # a bad request (mis-shaped readmission fields, ...) must
+                # fail alone instead of poisoning the round or leaving its
+                # sid queued or running forever
+                self._fail(slot, entry, e)
+                continue
+            admits.append((slot, entry, params, state))
 
     # -- stepping -------------------------------------------------------------
     def _chunk_size(self, max_chunk: int | None) -> int:
@@ -401,9 +433,9 @@ class SimulationFarm:
         # goes bad in the chunk that would also have finished it reports
         # "diverged", not a healthy-looking "steps" result
         self._drain_health()
-        for slot, entry in list(self.table.occupied()):
-            if entry.steps_done >= entry.req.steps:
-                self._finish(slot, entry, "steps")
+        self._harvest([(slot, entry, "steps")
+                       for slot, entry in self.table.occupied()
+                       if entry.steps_done >= entry.req.steps])
         self._check_steady(resid)
         return chunk
 
@@ -470,39 +502,46 @@ class SimulationFarm:
         if self.device_steps % self.check_steady_every:
             return
         with self.tel.section("farm.check_steady"):
+            done = []
             if resid is not None:
-                for slot, entry in list(self.table.occupied()):
+                for slot, entry in self.table.occupied():
                     tol = entry.req.residual_tol
                     if tol is not None and float(resid[slot]) <= tol:
-                        self._finish(slot, entry, "residual")
+                        done.append((slot, entry, "residual"))
+            stopped = {slot for slot, _, _ in done}
             watched = [(s, e) for s, e in self.table.occupied()
-                       if e.req.steady_tol is not None]
-            if not watched:
-                return
-            ke = self.exec.kinetic_energy()
-            for slot, entry in watched:
-                k = float(ke[slot])
-                prev = entry.ke_prev
-                entry.ke_prev = k
-                if prev is not None and abs(k - prev) <= \
-                        entry.req.steady_tol * max(abs(k), 1e-12):
-                    self._finish(slot, entry, "steady")
+                       if e.req.steady_tol is not None and s not in stopped]
+            if watched:
+                ke = self.exec.kinetic_energy()
+                for slot, entry in watched:
+                    k = float(ke[slot])
+                    prev = entry.ke_prev
+                    entry.ke_prev = k
+                    if prev is not None and abs(k - prev) <= \
+                            entry.req.steady_tol * max(abs(k), 1e-12):
+                        done.append((slot, entry, "steady"))
+            self._harvest(done)
 
-    def _finish(self, slot: int, entry: _SlotEntry, reason: str):
-        req = entry.req
+    def _harvest(self, done: list):
+        """Resolve a round's finished sims, ``(slot, entry, reason)``
+        each: their fields come off the device in one ``read_slots``."""
+        if not done:
+            return
         with self.tel.section("farm.harvest"):
-            self.results[req.sid] = SimResult(
-                sid=req.sid, tag=req.tag, steps_done=entry.steps_done,
-                terminated=reason, state=self.exec.read_slot(slot),
-                config=req.config)
-            self._live.discard(req.sid)
-            self.table.release(slot)
-            self.exec.clear_slot(slot)
-            if self.monitor is not None:
-                self.monitor.release(req.sid)
-            self._resolved(req, entry.steps_done, reason)
-            if self.on_transition is not None:
-                self.on_transition("done", req, self.results[req.sid])
+            states = self.exec.read_slots([slot for slot, _, _ in done])
+            for (slot, entry, reason), state in zip(done, states):
+                req = entry.req
+                self.results[req.sid] = SimResult(
+                    sid=req.sid, tag=req.tag, steps_done=entry.steps_done,
+                    terminated=reason, state=state, config=req.config)
+                self._live.discard(req.sid)
+                self.table.release(slot)
+                self.exec.clear_slot(slot)
+                if self.monitor is not None:
+                    self.monitor.release(req.sid)
+                self._resolved(req, entry.steps_done, reason)
+                if self.on_transition is not None:
+                    self.on_transition("done", req, self.results[req.sid])
 
     def _fail(self, slot: int, entry: _SlotEntry, exc: BaseException):
         """Record a per-sim failure as a harvestable result and free the

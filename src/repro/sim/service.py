@@ -310,8 +310,8 @@ class SimulationService:
         The restored fields stay HOST-side while the request waits in the
         queue (readmission frees no slot by itself, and pinning a full
         state on-device would re-take the memory eviction just released);
-        on a decomposed (slots × shards) farm ``write_slot`` scatters them
-        to the shard layout at admission time.
+        on a decomposed (slots × shards) farm ``write_slots`` scatters
+        them to the shard layout at admission time.
         """
         ev = self._evicted.get(sid)
         if ev is None:

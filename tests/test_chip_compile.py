@@ -87,3 +87,36 @@ def test_3dblock_jacobi_compiles(one_chip):
                                    tile=tile)
 
     jax.jit(sweep).lower(p, rhs).compile()
+
+
+def test_slot_io_programs_compile_in_place(one_chip):
+    """The farm's slot I/O at the benchmark's size (256 cavity slots of
+    128 x 128 x 4): the fresh-admission update aliases the resident batch
+    (donated, no copy of it), and the harvest gathers and the readmission
+    scatter compile at the shortest and the longest round."""
+    from repro.sim import ensemble
+
+    n = 256
+    solver = NavierStokes3D(cavity.config(128))
+    one = jax.eval_shape(solver.init_state)
+    state = {k: jax.ShapeDtypeStruct((n,) + v.shape, v.dtype,
+                                     sharding=one_chip)
+             for k, v in one.items()}
+    out = {k: one_chip for k in state}
+    mask = jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip)
+    fresh = jax.jit(ensemble._write_fresh, donate_argnums=0,
+                    out_shardings=out).lower(
+        state, _shapes(one, one_chip), mask).compile()
+    batch = sum(v.size * v.dtype.itemsize for v in state.values())
+    assert fresh.memory_analysis().alias_size_in_bytes == batch
+    dynamic = {k: state[k] for k in NavierStokes3D.FIELDS}
+    rows = jax.jit(ensemble._write_rows, donate_argnums=0, out_shardings=out)
+    for b in (1, n):
+        idx = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
+        got = ensemble._GATHER.lower(dynamic, idx).compile()
+        assert got.memory_analysis().output_size_in_bytes >= \
+            b * sum(v.size // n * v.dtype.itemsize for v in dynamic.values())
+        rows.lower(state, idx, {
+            k: jax.ShapeDtypeStruct((b,) + v.shape[1:], v.dtype,
+                                    sharding=one_chip)
+            for k, v in state.items()}).compile()

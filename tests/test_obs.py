@@ -274,7 +274,8 @@ class TestFarmTelemetry:
         snap = rt.telemetry.timers.snapshot()
         assert {"farm.admit", "farm.step_chunk", "farm.harvest"} <= set(snap)
         assert snap["farm.step_chunk"]["count"] >= 1
-        assert "ensemble.write_slot" in snap["farm.admit"]["children"]
+        assert "ensemble.write_slots" in snap["farm.admit"]["children"]
+        assert "ensemble.read_slots" in snap["farm.harvest"]["children"]
 
     def test_metrics_cover_the_farm_load(self, run_rt):
         rt, sids = run_rt
@@ -794,6 +795,7 @@ class TestResolve:
 # ---------------------------------------------------------------------------
 STAGES = ("update_velocity", "divergence", "jacobi", "project",
           "exchange_pad")
+DYNAMIC = ("vx", "vy", "vz", "p")   # the fields the step writes
 
 
 def _farm_run(telemetry):
@@ -825,18 +827,76 @@ class TestSpans:
         names = {n for n, _ in seen}
         assert names <= set(obs.SPANS), names - set(obs.SPANS)
         assert {"service.run", "farm.admit", "farm.step_chunk",
-                "farm.harvest", "ensemble.write_slot", "ensemble.read_slot",
+                "farm.harvest", "ensemble.write_slots", "ensemble.read_slots",
                 "runtime.step"} <= names
         assert all(n.startswith(("service.", "farm.", "ensemble.",
                                  "runtime.", "schedule."))
                    for n in obs.SPANS)
         fields = svc.farm.exec.state
-        want = {"transfers": len(fields),
-                "bytes": sum(v[0].size * v.dtype.itemsize
-                             for v in fields.values())}
-        reads = [c for n, c in seen if n == "ensemble.read_slot"]
-        assert len(reads) == 3 and all(c == want for c in reads)
-        assert want["transfers"] == 7   # vx, vy, vz, p and three masks
+        assert len(fields) == 7   # vx, vy, vz, p and three masks
+        member = sum(fields[f][0].size * fields[f].dtype.itemsize
+                     for f in DYNAMIC)
+        # two rounds through two slots: both members, then the third;
+        # one copy per field the step writes, whatever the round's size
+        reads = [c for n, c in seen if n == "ensemble.read_slots"]
+        assert reads == [{"members": 2, "transfers": 4, "bytes": 2 * member},
+                         {"members": 1, "transfers": 4, "bytes": member}]
+        writes = [c for n, c in seen if n == "ensemble.write_slots"]
+        assert writes == [{"members": 2, "transfers": 0, "bytes": 0},
+                          {"members": 1, "transfers": 0, "bytes": 0}]
+
+    @pytest.mark.parametrize("members", [1, 3, 4])
+    def test_read_slots_copies_each_dynamic_field_once(self, monkeypatch,
+                                                       members):
+        seen = []
+        real = obs.span
+        monkeypatch.setattr(obs, "span", lambda name, **counts: (
+            seen.append((name, counts)), real(name, **counts))[1])
+        svc = SimulationService(cavity.config(N, **KW), n_slots=4)
+        for i in range(members):
+            svc.submit(cavity.sim_request(N, re=100.0 + 50 * i, steps=3,
+                                          **KW))
+        svc.run(3)
+        reads = [c for n, c in seen if n == "ensemble.read_slots"]
+        assert [(c["members"], c["transfers"]) for c in reads] == \
+            [(members, len(DYNAMIC))]
+        assert all(set(svc.result(sid).state) == set(svc.farm.exec.state)
+                   for sid in range(members))
+
+    def test_admission_reads_nothing_back_per_member(self, monkeypatch):
+        """A round of fresh admissions installs host scalars: no device
+        value is converted to a host one (the per-slot scalars used to be
+        device scalars read back one by one)."""
+        from repro.cfd.ns3d import params_from_config
+        from repro.sim import ensemble
+
+        reads = []
+
+        class CountingNumpy:
+            """``numpy``, counting its host conversions of device arrays."""
+
+            def __getattr__(self, name):
+                fn = getattr(np, name)
+                if name not in ("asarray", "array", "stack", "float32"):
+                    return fn
+
+                def counted(*args, **kw):
+                    if any(isinstance(x, jax.Array)
+                           for x in jax.tree.leaves((args, kw))):
+                        reads.append(name)
+                    return fn(*args, **kw)
+                return counted
+
+        svc = SimulationService(cavity.config(N, **KW), n_slots=4)
+        for re_ in (70.0, 150.0, 300.0, 450.0):
+            svc.submit(cavity.sim_request(N, re=re_, steps=2, **KW))
+        monkeypatch.setattr(ensemble, "np", CountingNumpy())
+        svc.farm._admit()
+        assert svc.farm.table.n_active == 4
+        assert reads == []
+        # the count sees a device scalar handed to the executor
+        svc.farm.exec.write_slot(0, params_from_config(svc.farm.base_config))
+        assert len(reads) == len(params_from_config(svc.farm.base_config))
 
     def test_span_carries_its_stats_into_the_trace(self, tmp_path):
         jax.profiler.start_trace(str(tmp_path))

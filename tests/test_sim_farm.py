@@ -1,6 +1,8 @@
 """Simulation farm: batched ensembles must reproduce serial runs exactly,
 slots must recycle through queued work, and the compile cache must hand out
 one executable per static signature."""
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
@@ -335,6 +337,136 @@ class TestEnsembleExecutor:
         ke = ex.kinetic_energy()
         assert ke.shape == (3,)
 
+
+def _recording(monkeypatch, farm):
+    """Record each round's slot I/O on ``farm``: the slots of every
+    ``read_slots`` call and the ``(slot, hosted?)`` pairs of every
+    ``write_slots`` call."""
+    reads, writes = [], []
+    ex = farm.exec
+    read, write = ex.read_slots, ex.write_slots
+
+    def read_slots(slots):
+        reads.append(list(slots))
+        return read(slots)
+
+    def write_slots(admits):
+        writes.append([(slot, state is not None)
+                       for slot, _, state in admits])
+        return write(admits)
+
+    monkeypatch.setattr(ex, "read_slots", read_slots)
+    monkeypatch.setattr(ex, "write_slots", write_slots)
+    return reads, writes
+
+
+class TestBatchedSlotIO:
+    """A round's admissions are one ``write_slots`` call and its harvests
+    one ``read_slots`` call; every member still equals its serial run in
+    all seven fields (the masks come from host copies kept at admission).
+    """
+
+    RES = (50.0, 80.0, 100.0, 150.0, 200.0, 250.0, 300.0, 400.0)
+    STEPS = 12
+
+    @pytest.fixture(scope="class")
+    def two_waves(self):
+        mp = pytest.MonkeyPatch()
+        farm = SimulationFarm(cavity.config(N, **KW), n_slots=4)
+        reads, writes = _recording(mp, farm)
+        sids = {farm.submit(cavity.sim_request(N, re=re, steps=self.STEPS,
+                                               **KW)): re for re in self.RES}
+        results = farm.run_until_drained()
+        mp.undo()
+        return sids, results, reads, writes
+
+    def test_every_slot_finishes_in_one_round(self, two_waves):
+        _, _, reads, writes = two_waves
+        assert [len(r) for r in reads] == [4, 4]
+        assert [len(w) for w in writes] == [4, 4]
+        assert not any(hosted for w in writes for _, hosted in w)
+
+    def test_harvested_states_bitwise_equal_serial(self, two_waves):
+        sids, results, _, _ = two_waves
+        for sid, re in sids.items():
+            ref = serial_reference(re, self.STEPS)
+            got = results[sid].state
+            assert set(got) == set(ref)
+            for f in ref:
+                np.testing.assert_array_equal(ref[f], got[f],
+                                              err_msg=f"re={re} {f}")
+
+    def test_round_mixing_fresh_and_a_readmission_bitwise(self,
+                                                          monkeypatch):
+        svc = SimulationService(cavity.config(N, **KW), n_slots=4)
+        a = svc.submit(cavity.sim_request(N, re=100.0, steps=24, **KW))
+        svc.run(6)
+        assert svc.evict(a)
+        fresh = {svc.submit(cavity.sim_request(N, re=re, steps=10, **KW)):
+                 re for re in (150.0, 200.0, 300.0)}
+        assert svc.readmit(a)
+        reads, writes = _recording(monkeypatch, svc.farm)
+        svc.run(10)
+        assert writes[0] == [(0, False), (1, False), (2, False), (3, True)]
+        assert [len(r) for r in reads] == [3]
+        ra = svc.result(a)
+        assert ra.steps_done == 24
+        ref = serial_reference(100.0, 24)
+        for f in ref:
+            np.testing.assert_array_equal(ref[f], ra.state[f], err_msg=f)
+        for sid, re in fresh.items():
+            ref = serial_reference(re, 10)
+            for f in ref:
+                np.testing.assert_array_equal(
+                    ref[f], svc.result(sid).state[f], err_msg=f"{re} {f}")
+
+    def test_misshaped_readmission_fails_alone(self, monkeypatch):
+        farm = SimulationFarm(cavity.config(N, **KW), n_slots=3)
+        reads, writes = _recording(monkeypatch, farm)
+        good = {farm.submit(cavity.sim_request(N, re=re, steps=8, **KW)): re
+                for re in (100.0, 200.0)}
+        state = farm.exec.state_template()
+        state["p"] = np.zeros((N, N, 3), np.float32)
+        bad = farm.submit(dataclasses.replace(
+            cavity.sim_request(N, re=300.0, steps=8, **KW),
+            init_state=state, step0=2))
+        good[farm.submit(cavity.sim_request(N, re=400.0, steps=8,
+                                            **KW))] = 400.0
+        results = farm.run_until_drained()
+        assert results[bad].terminated == "failed"
+        assert "shape" in results[bad].error
+        # the bad request's slot took the next one in the same round
+        assert writes == [[(0, False), (1, False), (2, False)]]
+        assert [len(r) for r in reads] == [3]
+        for sid, re in good.items():
+            assert results[sid].terminated == "steps"
+            ref = serial_reference(re, 8)
+            for f in ref:
+                np.testing.assert_array_equal(ref[f], results[sid].state[f],
+                                              err_msg=f"re={re} {f}")
+
+
+    def test_one_shard_farm_bitwise_equal_plain_farm(self):
+        """Two rounds of four on a one-shard slots x shards mesh: each
+        round's batched write and read keep the plain farm's results."""
+        from repro.launch.mesh import make_mesh
+
+        dkw = dict(KW, decomposition=((0, "shard"),))
+        mesh = make_mesh((1, 1), ("slot", "shard"))
+        out = []
+        for farm, kw in ((SimulationFarm(cavity.config(N, **dkw), n_slots=4,
+                                         mesh=mesh, slot_axis="slot"), dkw),
+                         (SimulationFarm(cavity.config(N, **KW), n_slots=4),
+                          KW)):
+            sids = [farm.submit(cavity.sim_request(N, re=re, steps=10, **kw))
+                    for re in self.RES]
+            results = farm.run_until_drained()
+            out.append([results[sid].state for sid in sids])
+        for sharded, plain in zip(*out):
+            assert set(sharded) == set(plain)
+            for f in plain:
+                np.testing.assert_array_equal(sharded[f], plain[f],
+                                              err_msg=f)
 
 class TestDecompositionDegrade:
     """Fast-lane (1-CPU) coverage of the slots × shards plumbing: a mesh
@@ -780,6 +912,8 @@ class TestMultiDeviceFarm:
         from tests.helpers import run_with_devices
 
         script = """
+import dataclasses
+
 import numpy as np
 from repro.cfd import cavity
 from repro.launch.mesh import make_mesh

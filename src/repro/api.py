@@ -278,9 +278,20 @@ class Runtime:
         tel = self.telemetry if self.telemetry.enabled else None
         state = sched.compile_bin("INITIAL", telemetry=tel)({})
         evolve = sched.compile_bin("EVOLVE", telemetry=tel)
+        # a decomposed step's halo traffic per device, counted once here
+        # from the plan and carried by every step's span as its stats
+        halo = {}
+        if active:
+            from repro.obs import perf
+
+            halo = dict(
+                halo_bytes=perf.halo_bytes_per_step(
+                    solver_cfg, active, dict(self.mesh.shape)),
+                halo_permutes=perf.halo_permutes_per_step(solver_cfg,
+                                                          active))
 
         def step(st: dict) -> dict:
-            with obs.span("runtime.step"):
+            with obs.span("runtime.step", **halo):
                 return evolve(st)
 
         pr = PreparedRun(scenario=sc, solver=solver, schedule=sched,

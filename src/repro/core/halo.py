@@ -114,8 +114,9 @@ def _pad_axis(u: jnp.ndarray, width, spec: AxisSpec) -> jnp.ndarray:
             ghost_lo = strip_hi_lo if spec.periodic else apply_bc(spec.bc_lo, my_lo, "lo")
         else:
             n = lax.axis_size(spec.mesh_axis)
-            ghost_lo = lax.ppermute(
-                strip_hi_lo, spec.mesh_axis, _shift_perm(n, +1, spec.periodic))
+            with jax.named_scope("halo_permute"):
+                ghost_lo = lax.ppermute(strip_hi_lo, spec.mesh_axis,
+                                        _shift_perm(n, +1, spec.periodic))
             if not spec.periodic:
                 idx = lax.axis_index(spec.mesh_axis)
                 ghost_lo = jnp.where(idx == 0, apply_bc(spec.bc_lo, my_lo, "lo"), ghost_lo)
@@ -127,8 +128,9 @@ def _pad_axis(u: jnp.ndarray, width, spec: AxisSpec) -> jnp.ndarray:
             ghost_hi = strip_lo_hi if spec.periodic else apply_bc(spec.bc_hi, my_hi, "hi")
         else:
             n = lax.axis_size(spec.mesh_axis)
-            ghost_hi = lax.ppermute(
-                strip_lo_hi, spec.mesh_axis, _shift_perm(n, -1, spec.periodic))
+            with jax.named_scope("halo_permute"):
+                ghost_hi = lax.ppermute(strip_lo_hi, spec.mesh_axis,
+                                        _shift_perm(n, -1, spec.periodic))
             if not spec.periodic:
                 idx = lax.axis_index(spec.mesh_axis)
                 ghost_hi = jnp.where(
@@ -146,7 +148,8 @@ def exchange_pad(
     stencils.  Must run inside ``shard_map`` when any spec names a mesh axis.
     Corner ghosts are produced correctly because later axes exchange the
     already-padded earlier axes (the standard two-phase corner trick).
-    Its ops carry the ``exchange_pad`` scope in their metadata.
+    Its ops carry the ``exchange_pad`` scope in their metadata, and each
+    ``collective-permute`` the nested ``halo_permute`` scope.
     """
     if len(widths) != len(specs):
         raise ValueError("widths and specs length mismatch")

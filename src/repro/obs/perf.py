@@ -147,42 +147,52 @@ def exchange_permute_bytes(local_shape, widths, active_axes,
     return total
 
 
+def _step_exchanges(config) -> list[tuple[tuple, int]]:
+    """``(widths, calls)`` of the ``exchange_pad`` calls of ONE ns3d step,
+    in the order of ``NavierStokes3D._step_local``: three velocity fields
+    at widths (1,1,1); three one-sided divergence pads ((1,0),)*3; the
+    Jacobi loop — ``max(jacobi_iters // max(fused_sweeps,1), 1)``
+    iterations padding ``p`` (and, when the communication-avoiding
+    smoother is on, also ``rhs``) at the sweep width; one one-sided
+    projection pad ((0,1),)*3."""
+    k = max(config.fused_sweeps, 1)
+    iters = max(config.jacobi_iters // k, 1)
+    sweeps = ((1, 1, 1), iters) if k <= 1 else ((k, k, k), 2 * iters)
+    return [((1, 1, 1), 3), (((1, 0),) * 3, 3), sweeps, (((0, 1),) * 3, 1)]
+
+
 def halo_bytes_per_step(config, active: dict, mesh_extents: dict, *,
                         slots_local: int = 1, itemsize: int = 4) -> int:
     """Analytic per-device ``collective-permute`` operand bytes of ONE
     decomposed ns3d step — the ground truth the HLO-predicted halo bytes
     are validated against.
 
-    Mirrors the exchange sequence of ``NavierStokes3D._step_local``:
-    three velocity fields at widths (1,1,1); three one-sided divergence
-    pads ((1,0),)*3; the Jacobi loop — ``max(jacobi_iters //
-    max(fused_sweeps,1), 1)`` iterations padding ``p`` (and, when the
-    communication-avoiding smoother is on, also ``rhs``) at the sweep
-    width; one one-sided projection pad ((0,1),)*3.  ``active`` maps array
-    axis -> mesh axis (``plan_decomposition``'s output); ``mesh_extents``
-    maps mesh axis -> extent; ``slots_local`` multiplies for the farm's
-    per-device resident slots (the vmapped batch dimension rides inside
-    every strip).  The in-situ health diagnostics add nothing here: their
-    divergence stencil is interior-only (ghost-free by construction), so
-    a health-monitored farm step moves exactly these bytes too.
+    Mirrors the exchange sequence of ``NavierStokes3D._step_local``
+    (:func:`_step_exchanges`).  ``active`` maps array axis -> mesh axis
+    (``plan_decomposition``'s output); ``mesh_extents`` maps mesh axis ->
+    extent; ``slots_local`` multiplies for the farm's per-device resident
+    slots (the vmapped batch dimension rides inside every strip).  The
+    in-situ health diagnostics add nothing here: their divergence stencil
+    is interior-only (ghost-free by construction), so a health-monitored
+    farm step moves exactly these bytes too.
     """
     local = list(config.shape)
     for ax, mesh_axis in active.items():
         local[ax] //= mesh_extents[mesh_axis]
     act = set(active)
-    k = max(config.fused_sweeps, 1)
-    iters = max(config.jacobi_iters // k, 1)
-    per_slot = 3 * exchange_permute_bytes(local, (1, 1, 1), act, itemsize)
-    per_slot += 3 * exchange_permute_bytes(local, ((1, 0),) * 3, act,
-                                           itemsize)
-    if k <= 1:
-        per_slot += iters * exchange_permute_bytes(local, (1, 1, 1), act,
-                                                   itemsize)
-    else:  # fused smoother pads p AND rhs at width k each iteration
-        per_slot += iters * 2 * exchange_permute_bytes(local, (k, k, k), act,
-                                                       itemsize)
-    per_slot += exchange_permute_bytes(local, ((0, 1),) * 3, act, itemsize)
+    per_slot = sum(calls * exchange_permute_bytes(local, widths, act,
+                                                  itemsize)
+                   for widths, calls in _step_exchanges(config))
     return per_slot * slots_local
+
+
+def halo_permutes_per_step(config, active: dict) -> int:
+    """``collective-permute`` ops ONE decomposed ns3d step runs per
+    device: one per decomposed axis and nonzero ghost side of each
+    ``exchange_pad`` call (a farm's slots ride inside the same ops)."""
+    return sum(calls * sum(bool(side) for ax, w in enumerate(widths)
+                           if ax in active for side in _norm_w(w))
+               for widths, calls in _step_exchanges(config))
 
 
 def decomposed_step_hlo(config, *, n_slots: int, mesh_axes,

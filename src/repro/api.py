@@ -278,20 +278,25 @@ class Runtime:
         tel = self.telemetry if self.telemetry.enabled else None
         state = sched.compile_bin("INITIAL", telemetry=tel)({})
         evolve = sched.compile_bin("EVOLVE", telemetry=tel)
-        # a decomposed step's halo traffic per device, counted once here
-        # from the plan and carried by every step's span as its stats
-        halo = {}
-        if active:
-            from repro.obs import perf
+        # a decomposed step's halo traffic per device, and the sweeps
+        # that still build a padded copy of p (a stat only where there
+        # are any: 3DBLOCK, the fused smoother), counted once here from
+        # the plan and carried by every step's span as its stats
+        from repro.obs import perf
 
-            halo = dict(
+        stats = {}
+        if active:
+            stats = dict(
                 halo_bytes=perf.halo_bytes_per_step(
                     solver_cfg, active, dict(self.mesh.shape)),
                 halo_permutes=perf.halo_permutes_per_step(solver_cfg,
                                                           active))
+        padded = perf.padded_sweeps_per_step(solver_cfg)
+        if padded:
+            stats["padded_sweeps"] = padded
 
         def step(st: dict) -> dict:
-            with obs.span("runtime.step", **halo):
+            with obs.span("runtime.step", **stats):
                 return evolve(st)
 
         pr = PreparedRun(scenario=sc, solver=solver, schedule=sched,

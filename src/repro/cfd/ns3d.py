@@ -27,9 +27,10 @@ import numpy as np
 from jax import lax
 
 from repro.core import AxisSpec, Domain, GridDriver, bc_dirichlet, bc_neumann
-from repro.core.halo import exchange_pad, stencil_step_overlap
+from repro.core.halo import (exchange_pad, shifted_neighbours,
+                             stencil_step_overlap)
 from repro.kernels import ops, ref
-from repro.kernels.jacobi import jacobi_fused_ref
+from repro.kernels.jacobi import jacobi_fused_ref, jacobi_pressure_unpadded
 
 
 def bc_moving_wall(u_wall: float):
@@ -291,6 +292,12 @@ class NavierStokes3D:
         k = c.fused_sweeps
 
         def jacobi_body(_, pcur):
+            if k <= 1 and kw["template"] == "JNP":
+                # the seven-point sweep reads no corners: p's six face
+                # neighbours at its own shape, no padded copy of p
+                return jacobi_pressure_unpadded(
+                    pcur, shifted_neighbours(pcur, p_specs), rhs, h=h,
+                    omega=c.jacobi_omega)
             if k <= 1:
                 pp = exchange_pad(pcur, (1, 1, 1), p_specs)
                 return ops.jacobi_pressure(pp, rhs, h=h, omega=c.jacobi_omega, **kw)

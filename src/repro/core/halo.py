@@ -88,11 +88,21 @@ def _norm_width(w) -> tuple[int, int]:
     return (int(lo), int(hi))
 
 
-def _pad_axis(u: jnp.ndarray, width, spec: AxisSpec) -> jnp.ndarray:
-    """Fill ghosts along one axis: neighbor exchange + physical BCs."""
-    wlo, whi = _norm_width(width)
-    if wlo == 0 and whi == 0:
-        return u
+def _apply_bc(rule: BCRule | None, strip: jnp.ndarray, side: str,
+              axis: int) -> jnp.ndarray:
+    if rule is None:
+        return jnp.zeros_like(strip)
+    rule.axis = axis  # let flip-based rules know the axis
+    return rule(strip, side)
+
+
+def _ghosts(u: jnp.ndarray, wlo: int, whi: int, spec: AxisSpec
+            ) -> tuple[jnp.ndarray | None, jnp.ndarray | None]:
+    """The ``wlo``- and ``whi``-wide ghost slabs of ``u`` along one axis
+    (``None`` for a zero width): a periodic wrap on an undecomposed
+    periodic axis, the BC rule on a wall, a ``ppermute`` from the
+    neighbouring shard on a mesh axis (the BC rule on a non-periodic
+    mesh axis's edge shards)."""
     ax = spec.array_axis
     size = u.shape[ax]
     if size < max(wlo, whi):
@@ -100,18 +110,13 @@ def _pad_axis(u: jnp.ndarray, width, spec: AxisSpec) -> jnp.ndarray:
             f"local extent {size} on axis {ax} smaller than halo width {(wlo, whi)}"
         )
 
-    def apply_bc(rule: BCRule | None, strip: jnp.ndarray, side: str) -> jnp.ndarray:
-        if rule is None:
-            return jnp.zeros_like(strip)
-        rule.axis = ax  # let flip-based rules know the axis
-        return rule(strip, side)
-
-    parts = [u]
+    ghost_lo = ghost_hi = None
     if wlo:
         strip_hi_lo = lax.slice_in_dim(u, size - wlo, size, axis=ax)  # sent right
         my_lo = lax.slice_in_dim(u, 0, wlo, axis=ax)
         if spec.mesh_axis is None:
-            ghost_lo = strip_hi_lo if spec.periodic else apply_bc(spec.bc_lo, my_lo, "lo")
+            ghost_lo = (strip_hi_lo if spec.periodic
+                        else _apply_bc(spec.bc_lo, my_lo, "lo", ax))
         else:
             n = lax.axis_size(spec.mesh_axis)
             with jax.named_scope("halo_permute"):
@@ -119,13 +124,14 @@ def _pad_axis(u: jnp.ndarray, width, spec: AxisSpec) -> jnp.ndarray:
                                         _shift_perm(n, +1, spec.periodic))
             if not spec.periodic:
                 idx = lax.axis_index(spec.mesh_axis)
-                ghost_lo = jnp.where(idx == 0, apply_bc(spec.bc_lo, my_lo, "lo"), ghost_lo)
-        parts.insert(0, ghost_lo)
+                ghost_lo = jnp.where(
+                    idx == 0, _apply_bc(spec.bc_lo, my_lo, "lo", ax), ghost_lo)
     if whi:
         strip_lo_hi = lax.slice_in_dim(u, 0, whi, axis=ax)  # sent left
         my_hi = lax.slice_in_dim(u, size - whi, size, axis=ax)
         if spec.mesh_axis is None:
-            ghost_hi = strip_lo_hi if spec.periodic else apply_bc(spec.bc_hi, my_hi, "hi")
+            ghost_hi = (strip_lo_hi if spec.periodic
+                        else _apply_bc(spec.bc_hi, my_hi, "hi", ax))
         else:
             n = lax.axis_size(spec.mesh_axis)
             with jax.named_scope("halo_permute"):
@@ -134,9 +140,18 @@ def _pad_axis(u: jnp.ndarray, width, spec: AxisSpec) -> jnp.ndarray:
             if not spec.periodic:
                 idx = lax.axis_index(spec.mesh_axis)
                 ghost_hi = jnp.where(
-                    idx == n - 1, apply_bc(spec.bc_hi, my_hi, "hi"), ghost_hi)
-        parts.append(ghost_hi)
-    return jnp.concatenate(parts, axis=ax) if len(parts) > 1 else u
+                    idx == n - 1, _apply_bc(spec.bc_hi, my_hi, "hi", ax), ghost_hi)
+    return ghost_lo, ghost_hi
+
+
+def _pad_axis(u: jnp.ndarray, width, spec: AxisSpec) -> jnp.ndarray:
+    """Fill ghosts along one axis: neighbor exchange + physical BCs."""
+    wlo, whi = _norm_width(width)
+    if wlo == 0 and whi == 0:
+        return u
+    ghost_lo, ghost_hi = _ghosts(u, wlo, whi, spec)
+    parts = [g for g in (ghost_lo, u, ghost_hi) if g is not None]
+    return jnp.concatenate(parts, axis=spec.array_axis)
 
 
 def exchange_pad(
@@ -157,6 +172,64 @@ def exchange_pad(
         for w, spec in zip(widths, specs):
             u = _pad_axis(u, w, spec)
     return u
+
+
+def _shift(u: jnp.ndarray, axis: int, k: int) -> jnp.ndarray:
+    """``v[i] = u[i - k]`` along ``axis`` where that row exists, else 0."""
+    cfg = [(0, 0, 0)] * u.ndim
+    cfg[axis] = (k, -k, 0)
+    return lax.pad(u, jnp.zeros((), u.dtype), cfg)
+
+
+def _edge_ghost(rule: BCRule | None, u: jnp.ndarray, side: str,
+                axis: int) -> jnp.ndarray:
+    """The one-wide ghost that ``rule`` gives, for every row of ``u``.
+
+    A one-wide strip is its own mirror image, so a rule acts on it value
+    by value: run on ``u`` seen as a stack of one-wide strips, it gives at
+    each edge row exactly the ghost :func:`_ghosts` would, with no strip
+    cut out of ``u``."""
+    strips = jnp.expand_dims(u, axis)
+    return jnp.squeeze(_apply_bc(rule, strips, side, axis), axis)
+
+
+def shifted_neighbours(
+    u: jnp.ndarray, specs: Sequence[AxisSpec]
+) -> list[tuple[jnp.ndarray, jnp.ndarray]]:
+    """``u``'s two face neighbours along each spec's axis, at ``u``'s shape.
+
+    For axis ``a`` the pair is ``(u_minus, u_plus)`` with
+    ``u_minus[i] = u[i-1]`` and ``u_plus[i] = u[i+1]`` along ``a``; the
+    row past each end is the one-wide ghost that :func:`exchange_pad`
+    would fill.  On an undecomposed axis that ghost is read from ``u``
+    itself: its far row on a periodic axis, the BC rule of its edge row
+    on a wall (:func:`_edge_ghost`).  On a mesh axis it is the face of
+    :func:`_ghosts` (same ``ppermute``, same BC on the edge shards).  A
+    stencil that reads no corners (the seven-point Jacobi sweep) needs
+    nothing else, so no padded copy of ``u`` and no ghost strip of an
+    undecomposed axis is built: the reads fuse into the stencil's single
+    pass at the output's shape.  Its ops carry the ``exchange_pad``
+    scope, its ``collective-permute``s the nested ``halo_permute`` scope.
+    """
+    out = []
+    with jax.named_scope("exchange_pad"):
+        for spec in specs:
+            ax = spec.array_axis
+            size = u.shape[ax]
+            if spec.mesh_axis is not None:
+                one_axis = _pad_axis(u, 1, spec)
+                out.append((lax.slice_in_dim(one_axis, 0, size, axis=ax),
+                            lax.slice_in_dim(one_axis, 2, size + 2, axis=ax)))
+                continue
+            if spec.periodic:
+                lo, hi = _shift(u, ax, 1 - size), _shift(u, ax, size - 1)
+            else:
+                lo = _edge_ghost(spec.bc_lo, u, "lo", ax)
+                hi = _edge_ghost(spec.bc_hi, u, "hi", ax)
+            row = lax.broadcasted_iota(jnp.int32, u.shape, ax)
+            out.append((jnp.where(row == 0, lo, _shift(u, ax, 1)),
+                        jnp.where(row == size - 1, hi, _shift(u, ax, -1))))
+    return out
 
 
 def stencil_step_overlap(

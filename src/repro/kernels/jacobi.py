@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.generator import element_block_spec
+from repro.core.generator import KernelContext, element_block_spec
+from repro.kernels import stencil3d
 
 
 def _sweep(p, rhs, h2, omega):
@@ -84,3 +85,31 @@ def jacobi_fused(
         out_shape=jax.ShapeDtypeStruct(interior, p.dtype),
         interpret=interpret,
     )(p, rhs)
+
+
+class _Shifted:
+    """A kernel body's view of an unpadded field: ``c`` is the field, and
+    ``at`` serves the unit offsets from its precomputed face neighbours."""
+
+    def __init__(self, c, neighbours=()):
+        self.c = c
+        self._at = {(0, 0, 0): c}
+        for axis, pair in enumerate(neighbours):
+            for step, arr in zip((-1, 1), pair):
+                off = [0, 0, 0]
+                off[axis] = step
+                self._at[tuple(off)] = arr
+
+    def at(self, dx: int = 0, dy: int = 0, dz: int = 0):
+        return self._at[(dx, dy, dz)]
+
+
+def jacobi_pressure_unpadded(p, neighbours, rhs, *, h, omega=1.0):
+    """One JACOBI_PRESSURE sweep over unpadded ``p``, reading its six face
+    neighbours from ``neighbours`` (``[(minus, plus)]`` per axis, each at
+    ``p``'s shape, as ``core.halo.shifted_neighbours`` gives them).  It
+    runs the descriptor's own body, so the arithmetic and its order are
+    those of the padded sweep, and the result is bitwise the same."""
+    ctx = KernelContext({"p": _Shifted(p, neighbours), "rhs": _Shifted(rhs)},
+                        {"h": h, "omega": omega})
+    return stencil3d.jacobi_pressure_body(ctx)["p"]

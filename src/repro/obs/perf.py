@@ -123,7 +123,7 @@ def _norm_w(w) -> tuple[int, int]:
 
 
 def exchange_permute_bytes(local_shape, widths, active_axes,
-                           itemsize: int = 4) -> int:
+                           itemsize: int = 4, *, padded: bool = True) -> int:
     """Per-device ``collective-permute`` operand bytes of ONE
     ``exchange_pad(u, widths, specs)`` call.
 
@@ -131,7 +131,8 @@ def exchange_permute_bytes(local_shape, widths, active_axes,
     (later axes exchange strips of the already-padded earlier axes — the
     corner trick), each decomposed axis side ships one strip of width
     ``w`` at the CURRENT padded shape, and non-decomposed axes still grow
-    the shape by their BC padding.
+    the shape by their BC padding.  ``padded=False`` counts
+    ``halo.shifted_neighbours`` instead: every face at the unpadded shape.
     """
     shape = list(local_shape)
     total = 0
@@ -143,22 +144,38 @@ def exchange_permute_bytes(local_shape, widths, active_axes,
                     strip = list(shape)
                     strip[ax] = side
                     total += math.prod(strip) * itemsize
-        shape[ax] += lo + hi
+        if padded:
+            shape[ax] += lo + hi
     return total
 
 
-def _step_exchanges(config) -> list[tuple[tuple, int]]:
-    """``(widths, calls)`` of the ``exchange_pad`` calls of ONE ns3d step,
-    in the order of ``NavierStokes3D._step_local``: three velocity fields
-    at widths (1,1,1); three one-sided divergence pads ((1,0),)*3; the
+def padded_sweeps_per_step(config) -> int:
+    """Jacobi sweeps of ONE ns3d step that build a padded copy of ``p``.
+
+    None on the jnp template's single sweeps, which read ``p``'s face
+    neighbours at its own shape (``halo.shifted_neighbours``); every loop
+    trip under 3DBLOCK or the fused smoother (``fused_sweeps > 1``)."""
+    k = max(config.fused_sweeps, 1)
+    if k == 1 and (config.template or "JNP") == "JNP":
+        return 0
+    return max(config.jacobi_iters // k, 1)
+
+
+def _step_exchanges(config) -> list[tuple[tuple, int, bool]]:
+    """``(widths, calls, padded)`` of the ghost fills of ONE ns3d step, in
+    the order of ``NavierStokes3D._step_local``: three velocity fields at
+    widths (1,1,1); three one-sided divergence pads ((1,0),)*3; the
     Jacobi loop — ``max(jacobi_iters // max(fused_sweeps,1), 1)``
-    iterations padding ``p`` (and, when the communication-avoiding
-    smoother is on, also ``rhs``) at the sweep width; one one-sided
-    projection pad ((0,1),)*3."""
+    iterations filling ``p``'s one-wide faces, padded or not
+    (:func:`padded_sweeps_per_step`), or, when the communication-avoiding
+    smoother is on, padding ``p`` and ``rhs`` at the sweep width; one
+    one-sided projection pad ((0,1),)*3."""
     k = max(config.fused_sweeps, 1)
     iters = max(config.jacobi_iters // k, 1)
-    sweeps = ((1, 1, 1), iters) if k <= 1 else ((k, k, k), 2 * iters)
-    return [((1, 1, 1), 3), (((1, 0),) * 3, 3), sweeps, (((0, 1),) * 3, 1)]
+    sweeps = (((1, 1, 1), iters, padded_sweeps_per_step(config) > 0)
+              if k <= 1 else ((k, k, k), 2 * iters, True))
+    return [((1, 1, 1), 3, True), (((1, 0),) * 3, 3, True), sweeps,
+            (((0, 1),) * 3, 1, True)]
 
 
 def halo_bytes_per_step(config, active: dict, mesh_extents: dict, *,
@@ -181,8 +198,8 @@ def halo_bytes_per_step(config, active: dict, mesh_extents: dict, *,
         local[ax] //= mesh_extents[mesh_axis]
     act = set(active)
     per_slot = sum(calls * exchange_permute_bytes(local, widths, act,
-                                                  itemsize)
-                   for widths, calls in _step_exchanges(config))
+                                                  itemsize, padded=padded)
+                   for widths, calls, padded in _step_exchanges(config))
     return per_slot * slots_local
 
 
@@ -192,7 +209,7 @@ def halo_permutes_per_step(config, active: dict) -> int:
     ``exchange_pad`` call (a farm's slots ride inside the same ops)."""
     return sum(calls * sum(bool(side) for ax, w in enumerate(widths)
                            if ax in active for side in _norm_w(w))
-               for widths, calls in _step_exchanges(config))
+               for widths, calls, _ in _step_exchanges(config))
 
 
 def decomposed_step_hlo(config, *, n_slots: int, mesh_axes,

@@ -7,15 +7,18 @@ one that no longer fits its 16 GB.  Everything built from the topology is
 built in fixtures, never at import: only the test worker that runs this
 file loads the TPU library.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.cfd import cavity, taylor_green
-from repro.cfd.ns3d import PARAM_KEYS, NavierStokes3D
+from repro.cfd.ns3d import PARAM_KEYS, NavierStokes3D, params_from_config
 from repro.core import autotune
 from repro.core.rooflinemodel import V5E
 from repro.kernels import ops, stencil3d
@@ -52,6 +55,39 @@ def _shapes(tree, sharding):
             for k, v in tree.items()}
 
 
+def _jacobi_loop_arrays(hlo: str) -> list[tuple[tuple, int]]:
+    """``(dims, lane_dim)`` of every array the compiled Jacobi loop
+    materialises: the results of its body's instructions."""
+    bodies = {re.search(r"body=%([\w.\-]+)", line).group(1)
+              for line in hlo.splitlines()
+              if " while(" in line and "/jacobi/" in line}
+    assert bodies, "no Jacobi while loop in the compiled step"
+    arrays, current = [], None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            current = head.group(1)
+        elif current in bodies and " = " in line:
+            result = line.split(" = ", 1)[1].split(" ", 1)[0]
+            if result.startswith("("):   # a multi-output fusion's tuple
+                result = line.split(" = ", 1)[1].split(") ", 1)[0]
+            for dims, layout in re.findall(
+                    r"f32\[([\d,]*)\]\{([\d,]*)", result):
+                dims = tuple(int(d) for d in dims.split(",") if d)
+                if dims:
+                    arrays.append((dims, int(layout.split(",")[0])))
+    return arrays
+
+
+def _assert_sweeps_read_unpadded_p(compiled, local_shape):
+    """No array of the loop is a padded p (an axis ``n + 2`` wide) or a
+    one-wide ghost strip on the lane axis."""
+    padded = {n + 2 for n in local_shape}
+    for dims, lane in _jacobi_loop_arrays(compiled.as_text()):
+        assert not padded & set(dims), (dims, local_shape)
+        assert dims[lane] > 1 or all(d == 1 for d in dims), dims
+
+
 @pytest.mark.parametrize("config", [
     pytest.param(lambda: taylor_green.config(256, nz=256), id="taylor_green-256^3"),
     pytest.param(lambda: cavity.config(256), id="cavity-256x256x4"),
@@ -67,6 +103,29 @@ def test_jnp_step_compiles_and_fits_one_chip(one_chip, config):
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert 0 < used <= V5E.hbm_bytes
+    _assert_sweeps_read_unpadded_p(compiled, solver.config.shape)
+
+
+def test_x_split_768_sweeps_read_unpadded_p(topo):
+    """768^3 split in x over the described 2x2: the sweeps read the
+    permuted x faces and p itself, with no 194-row p padded in x and no
+    770-wide rows."""
+    mesh = Mesh(topo.devices[:4], ("shard",))
+    cfg = dataclasses.replace(taylor_green.config(768, nz=768),
+                              template="JNP", decomposition=((0, "shard"),))
+    solver = NavierStokes3D(cfg, mesh)
+    example = jax.eval_shape(solver.init_state)
+    params = params_from_config(cfg)
+    field = NamedSharding(mesh, P("shard"))
+    shapes = _shapes(example, field)
+    scalars = {k: jax.ShapeDtypeStruct((), jnp.float32,
+                                       sharding=NamedSharding(mesh, P()))
+               for k in params}
+    step = solver.driver.sharded_step_tree(solver._step_local, example,
+                                           params)
+    compiled = step.lower(shapes, scalars).compile()
+    assert "collective-permute" in compiled.as_text()
+    _assert_sweeps_read_unpadded_p(compiled, (192, 768, 768))
 
 
 @pytest.mark.xfail(strict=True, reason="3DBLOCK does not lower for the TPU "

@@ -133,7 +133,7 @@ class NavierStokes3D:
 
         The serial path shards state as ``field_pspec``; the simulation
         farm stacks a slot axis in front and shards as
-        ``P(slot_axis, *field_pspec)`` (``dist.sharding.slot_field_spec``)
+        ``P(slot_axis, *field_pspec)`` (``sim.ensemble.slot_field_spec``)
         — same grid placement, one more batch dimension.
         """
         return self.domain.pspec()

@@ -1,18 +1,13 @@
-"""repro.dist — the distribution substrate.
+"""repro.dist — placement for the LM stack (models, train, serve).
 
-The paper's framework distributes structured-grid computation across a
-hybrid machine by pushing ALL placement decisions (domain decomposition,
-ghost-zone exchange, device mapping) into a substrate layer so application
-code stays serial-looking.  This package is that layer for the jax world:
-
-  sharding           — declarative PartitionSpec rules (FSDP×TP layouts,
-                       divisibility guards, batch/cache specs, mesh modes)
-  compression        — int8 error-feedback gradient allreduce for the
-                       slow (cross-pod / DCN-class) links
-  pipeline_parallel  — GPipe microbatch relay over a ``pod`` axis
+  sharding     — declarative PartitionSpec rules (FSDP×TP layouts,
+                 divisibility guards, batch/cache specs, mesh modes)
+  compression  — int8 error-feedback gradient allreduce for the
+                 slow (cross-pod / DCN-class) links
 
 Model/optimizer code never names a device: it receives a ``ShardCfg`` and
 spec trees built here, and the same numerics run single-device (mesh=None)
-or across a pod slice unchanged.
+or across a pod slice unchanged.  The CFD framework places its grids and
+farm slots itself (``core.driver``, ``sim.ensemble``) and imports nothing
+from this package.
 """
-from repro.dist import compression, pipeline_parallel, sharding  # noqa: F401

@@ -242,82 +242,6 @@ def batch_spec_tree(batch, mesh, shard: ShardCfg):
 
 
 # ---------------------------------------------------------------------------
-# ensemble / slot specs
-# ---------------------------------------------------------------------------
-def slot_spec(mesh, n_slots: int, axis: str = "data"):
-    """Spec placing a leading ensemble *slot* axis over a data-parallel
-    mesh axis (multi-device simulation farms: each device advances
-    ``n_slots / |axis|`` resident simulations).  Guarded like every other
-    rule: a non-divisible slot count stays replicated."""
-    if axis not in mesh.axis_names:
-        raise ValueError(f"mesh {mesh.axis_names} has no axis {axis!r}")
-    return P(_guard(mesh, axis, n_slots))
-
-
-def validate_decomposition(decomposition, n_axes: int, mesh_axis_names,
-                           slot_axis: str | None = None) -> tuple:
-    """Normalize + validate a grid decomposition: returns the
-    ``((array_axis, mesh_axis), ...)`` pairs, raising on a duplicate
-    array axis, an out-of-range array axis, an unknown mesh axis, or a
-    grid axis decomposing over the slot axis.  Shared by the spec rule
-    below and the farm's ``plan_decomposition`` so both layers enforce —
-    and word — the contract identically."""
-    pairs = tuple(decomposition.items() if isinstance(decomposition, dict)
-                  else decomposition)
-    if len({a for a, _ in pairs}) != len(pairs):
-        raise ValueError(
-            f"decomposition {pairs!r} maps some array axis more than "
-            "once; each grid axis decomposes over at most one mesh axis")
-    for a, name in pairs:
-        if not 0 <= int(a) < n_axes:
-            raise ValueError(
-                f"decomposition names array axis {a}, but fields have "
-                f"only {n_axes} grid axes")
-        if name not in mesh_axis_names:
-            raise ValueError(
-                f"mesh {tuple(mesh_axis_names)} has no axis {name!r} "
-                f"(decomposition of array axis {a})")
-        if slot_axis is not None and name == slot_axis:
-            raise ValueError(
-                f"axis {name!r} is the slot axis; a grid axis cannot "
-                "decompose over it")
-    return pairs
-
-
-def slot_field_spec(mesh, n_slots: int, shape: tuple, decomposition=(),
-                    slot_axis: str = "slot"):
-    """Spec for a slot-stacked grid field ``(n_slots, *shape)`` on a
-    slots × shards farm mesh: ``P(slot_axis, <grid axes>)``.
-
-    The two axes get different failure postures, deliberately:
-
-    * the slot axis is *guarded* — slots never interact, so a slot count
-      that does not divide over ``slot_axis`` runs replicated (correct,
-      just not parallel), same as :func:`slot_spec`;
-    * the grid axes *raise* — halo-exchange code inside the step ppermutes
-      over the decomposition's mesh axes assuming true shards, so quietly
-      replicating an indivisible grid axis would hand every device the
-      full extent while the exchange still shifts it: mis-sharding, not a
-      layout choice.
-    """
-    if slot_axis not in mesh.axis_names:
-        raise ValueError(f"mesh {mesh.axis_names} has no slot axis "
-                         f"{slot_axis!r}")
-    pairs = validate_decomposition(decomposition, len(shape),
-                                   mesh.axis_names, slot_axis=slot_axis)
-    grid: list = [None] * len(shape)
-    for a, name in pairs:
-        a = int(a)
-        if shape[a] % mesh.shape[name]:
-            raise ValueError(
-                f"grid extent {shape[a]} on array axis {a} is not "
-                f"divisible by mesh axis {name!r} (size "
-                f"{mesh.shape[name]}) — refusing to mis-shard")
-        grid[a] = name
-    return P(_guard(mesh, slot_axis, n_slots), *grid)
-
-
-# ---------------------------------------------------------------------------
 # NamedSharding lift
 # ---------------------------------------------------------------------------
 def named(specs, mesh):

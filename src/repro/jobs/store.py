@@ -300,9 +300,10 @@ class JobStore:
         Claimable: status in ``statuses`` AND no lease, an expired lease
         (dead owner -> *takeover*, counted), or this owner's own expired
         lease.  Ordered priority-descending then FIFO by job_id — the
-        same admission order the in-memory SlotTable uses.  Two processes
-        racing this method serialize on ``BEGIN IMMEDIATE``; a job can
-        never be leased twice while a lease is live.
+        same admission order the in-memory ``sim.slots.SlotTable`` uses.
+        Two processes racing this method serialize on ``BEGIN
+        IMMEDIATE``; a job can never be leased twice while a lease is
+        live.
         """
         now = time.time()
         marks = ",".join("?" * len(statuses))

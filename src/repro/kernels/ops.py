@@ -2,8 +2,8 @@
 
 Each op dispatches between the fused-jnp template (the XLA path, the default
 on every backend) and the Pallas 3DBLOCK template (asked for explicitly;
-interpret mode for CPU validation).  The CFD solver and the LM stack call
-these — never ``pallas_call`` directly.
+interpret mode for CPU validation).  The CFD solver calls these — never
+``pallas_call`` directly.
 """
 from __future__ import annotations
 
@@ -13,8 +13,6 @@ import jax.numpy as jnp
 
 from repro.core.generator import generate
 from repro.kernels import stencil3d
-from repro.kernels.attention import flash_attention
-from repro.kernels.jacobi import jacobi_fused, jacobi_fused_ref
 
 
 # JNP until tests/test_chip_compile.py::test_3dblock_jacobi_compiles passes
@@ -87,29 +85,3 @@ def project_velocity(vx, vy, vz, p, *, dt, h, **kw):
         dt=dt, h=h, **kw)
     return out["vx"], out["vy"], out["vz"]
 
-
-def jacobi_smooth(p, rhs, *, h, omega=1.0, sweeps=1, template=None,
-                  interpret=False, tile=(8, 8, 8)):
-    """Communication-avoiding fused smoother; inputs padded by ``sweeps``."""
-    tmpl = template or DEFAULT_TEMPLATE
-    if tmpl == "JNP":
-        return jacobi_fused_ref(p, rhs, h=h, omega=omega, sweeps=sweeps)
-    return jacobi_fused(p, rhs, h=h, omega=omega, sweeps=sweeps, tile=tile,
-                        interpret=interpret)
-
-
-def mha(q, k, v, *, causal=True, q_offset=0, template=None, interpret=False,
-        block_q=128, block_k=128):
-    """Attention hot-spot: Pallas flash kernel on TPU, else chunked XLA.
-
-    q: (H, Sq, D); k/v: (Hkv, Sk, D).
-    """
-    tmpl = template or DEFAULT_TEMPLATE
-    if tmpl == "3DBLOCK":
-        return flash_attention(q, k, v, causal=causal, q_offset=q_offset,
-                               block_q=block_q, block_k=block_k,
-                               interpret=interpret)
-    from repro.models.attention import chunked_mha  # lazy: avoid cycle
-
-    return chunked_mha(q, k, v, causal=causal, q_offset=q_offset,
-                       chunk=block_k)

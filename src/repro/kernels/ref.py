@@ -9,7 +9,6 @@ stencil radii; outputs are interior-shaped.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 
@@ -92,28 +91,3 @@ def project_velocity(vx, vy, vz, p, *, dt, h):
     return (vx - s * (pc(1, 0, 0) - pc()),
             vy - s * (pc(0, 1, 0) - pc()),
             vz - s * (pc(0, 0, 1) - pc()))
-
-
-# ---------------------------------------------------------------------------
-# attention oracle (for kernels/attention.py)
-# ---------------------------------------------------------------------------
-def mha_reference(q, k, v, *, causal=True, scale=None, q_offset=0):
-    """O(S^2)-memory reference attention.
-
-    q: (Sq, H, D), k/v: (Sk, Hkv, D) with H a multiple of Hkv (GQA).
-    ``q_offset``: absolute position of q[0] (for decode/causal masking).
-    """
-    sq, h, d = q.shape
-    sk, hkv, _ = k.shape
-    rep = h // hkv
-    kf = jnp.repeat(k, rep, axis=1)
-    vf = jnp.repeat(v, rep, axis=1)
-    scale = scale if scale is not None else 1.0 / jnp.sqrt(d).astype(q.dtype)
-    logits = jnp.einsum("qhd,khd->hqk", q.astype(jnp.float32),
-                        kf.astype(jnp.float32)) * scale
-    if causal:
-        qpos = jnp.arange(sq)[:, None] + q_offset
-        kpos = jnp.arange(sk)[None, :]
-        logits = jnp.where(kpos <= qpos, logits, -jnp.inf)
-    w = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("hqk,khd->qhd", w, vf.astype(jnp.float32)).astype(q.dtype)

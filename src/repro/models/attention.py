@@ -1,9 +1,8 @@
 """Attention: chunked-flash (pure XLA) + GQA module with KV cache.
 
 ``chunked_mha`` is the memory-safe O(S) attention used for training and
-prefill on every backend (the Pallas flash kernel in ``repro.kernels`` is
-the TPU fast path; both implement the same online-softmax algorithm and are
-cross-validated in tests).  Layout is BSHD: q (B, Sq, H, D), k/v
+prefill on every backend (online softmax over kv chunks, the algorithm of
+a flash-attention kernel).  Layout is BSHD: q (B, Sq, H, D), k/v
 (B, Skv, KH, D), H = KH * rep (GQA).
 
 Masking supports causal, causal-with-offset (decode), and prefix-LM
@@ -44,10 +43,10 @@ def full_mha(q, k, v, spec: MaskSpec = MaskSpec(), kv_valid_len=None,
              scale=None):
     """O(S^2)-memory attention (small-sequence / oracle / decode path).
 
-    The ``__kernel__`` scope marks the region as shipping as one fused
-    Pallas kernel on TPU (kernels/attention.py): the roofline's HBM-traffic
-    model charges only region inputs/outputs — logits/probabilities stay
-    in VMEM (see launch/hlo_cost.py).
+    The ``__kernel__`` scope marks the region the roofline prices as one
+    fused attention kernel: its HBM-traffic model charges only region
+    inputs/outputs — logits/probabilities stay in VMEM (see
+    launch/hlo_cost.py).
     """
     with jax.named_scope("__kernel__attention"):
         b, sq, h, d = q.shape

@@ -69,7 +69,7 @@ class ModelConfig:
     q_chunk: int = 1024
     # kv_chunk = full sequence: ONE kv pass per q-chunk, so the online-
     # softmax accumulator never round-trips HBM as a scan carry — the same
-    # HBM traffic as the Pallas flash kernel (which holds acc in VMEM and
+    # HBM traffic as a flash-attention kernel (which holds acc in VMEM and
     # streams kv in hardware-sized blocks).  Finite values model kernels
     # that spill the accumulator; used in ablations.
     kv_chunk: int = 1 << 30

@@ -143,12 +143,12 @@ def ssd_core(x, log_decay, in_scale, b_, c_, chunk: int, init_state=None,
         return None, final
     s_in = jnp.moveaxis(s_in, 0, 1)                            # (B,nc,G,R,N,P)
 
-    # intra-chunk quadratic + inter-chunk contribution: ships as the Pallas
-    # SSD kernel on TPU (kernels/ssd.py — the (L,L) decay/score temporaries
-    # stay in VMEM); the tagged jnp path below is the same math and is
-    # priced as that kernel by the roofline (DESIGN.md §6).
+    # intra-chunk quadratic + inter-chunk contribution: the tagged jnp path
+    # below is the math of the Pallas SSD kernel (models/ssd.py — the (L,L)
+    # decay/score temporaries stay in VMEM) and is priced as that kernel by
+    # the roofline (launch/hlo_cost.py).
     with jax.named_scope("__kernel__ssd"):
-        from repro.kernels.ssd import ssd_intra_reference
+        from repro.models.ssd import ssd_intra_reference
 
         y = ssd_intra_reference(xc, da, dtc, bc, cc, s_in)
     y = y.reshape(bsz, nc * l, g, r, p)[:, :s]

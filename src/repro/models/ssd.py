@@ -17,9 +17,10 @@ shapes.  MXU work is the (L,L)@(L,P) matmul per head.
 
 Validated in interpret mode against the pure-jnp oracle
 (`ssd_intra_reference` == the ssd_core intra-chunk math) over
-shape/dtype sweeps in tests/test_kernels_ssd.py.  The model code tags the
-jnp path with ``jax.named_scope("__kernel__ssd")`` so the dry-run roofline
-prices it as this kernel (see DESIGN.md §6).
+shape/dtype sweeps in tests/test_kernels_ssd.py.  The model code
+(``mamba2.ssd_core``) runs the reference, tagged with
+``jax.named_scope("__kernel__ssd")`` so the dry-run roofline prices it as
+this kernel (launch/hlo_cost.py).
 """
 from __future__ import annotations
 

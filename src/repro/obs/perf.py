@@ -271,7 +271,7 @@ def _find_sections(timers: dict, name: str) -> tuple[float, int]:
 
 def _slots_local(n_slots: int, slot_extent: int) -> int:
     """Resident slots per device: the slot axis divides when it can,
-    replicates otherwise (``dist.sharding.slot_spec``'s guard)."""
+    replicates otherwise (``sim.ensemble.slot_spec``'s guard)."""
     if slot_extent > 1 and n_slots % slot_extent == 0:
         return n_slots // slot_extent
     return n_slots

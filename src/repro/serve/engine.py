@@ -23,7 +23,7 @@ import numpy as np
 
 from repro.models import model
 from repro.models.config import LOCAL, ModelConfig, ShardCfg
-from repro.serve.slots import SlotTable
+from repro.sim.slots import SlotTable
 
 
 @dataclasses.dataclass

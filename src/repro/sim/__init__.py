@@ -6,8 +6,10 @@ parameter variants of one case resident on a slot axis, advanced by a single
 jitted vmapped step, with host-side admission/reclamation and a compile
 cache so new work of an already-seen shape never recompiles.
 
-    ensemble.py   the device layer — slot-stacked state, one step for all
+    ensemble.py   the device layer — slot-stacked state, one step for all,
+                  and the rules that place it on a mesh
     farm.py       the scheduler — queue, slots, termination, compile cache
+    slots.py      the admission table — fixed slots + FIFO queue
     service.py    the front-end — submit/poll/result + evict/readmit
     scenarios.py  the registry — declarative problem specs (repro.api)
 

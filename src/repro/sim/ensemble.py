@@ -14,11 +14,11 @@ finish (the analogue of multi-token speculation windows in LM serving).
 Two mesh placements compose (the farm's slots × shards story):
 
 * **slot parallelism** — the slot axis spreads over a data-parallel mesh
-  axis (``dist.sharding.slot_spec``); slots never interact, so the
+  axis (:func:`slot_spec`); slots never interact, so the
   distributed batch is bitwise the single-device one.
 * **per-slot grid decomposition** — with ``config.decomposition`` set,
   each slot's grid additionally decomposes over the named mesh axes
-  (``dist.sharding.slot_field_spec``), and the vmapped step runs the
+  (:func:`slot_field_spec`), and the vmapped step runs the
   driver's halo machinery (``exchange_pad`` / ``stencil_step_overlap``
   ppermuting over those axes) inside the same ``shard_map``.  One large
   simulation can then outgrow a single device while the farm keeps
@@ -52,6 +52,85 @@ def stack_trees(trees):
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
 
 
+# -- placement rules ---------------------------------------------------------
+def _slot_guard(mesh, axis: str, n_slots: int):
+    """``axis`` iff ``n_slots`` divides evenly over it (else replicated)."""
+    return axis if n_slots % mesh.shape[axis] == 0 else None
+
+
+def slot_spec(mesh, n_slots: int, axis: str = "data"):
+    """Spec placing a leading ensemble *slot* axis over a data-parallel
+    mesh axis (multi-device simulation farms: each device advances
+    ``n_slots / |axis|`` resident simulations).  Divisibility-guarded: a
+    non-divisible slot count stays replicated."""
+    if axis not in mesh.axis_names:
+        raise ValueError(f"mesh {mesh.axis_names} has no axis {axis!r}")
+    return P(_slot_guard(mesh, axis, n_slots))
+
+
+def validate_decomposition(decomposition, n_axes: int, mesh_axis_names,
+                           slot_axis: str | None = None) -> tuple:
+    """Normalize + validate a grid decomposition: returns the
+    ``((array_axis, mesh_axis), ...)`` pairs, raising on a duplicate
+    array axis, an out-of-range array axis, an unknown mesh axis, or a
+    grid axis decomposing over the slot axis.  Shared by
+    :func:`slot_field_spec` and :func:`plan_decomposition` so both enforce
+    — and word — the contract identically."""
+    pairs = tuple(decomposition.items() if isinstance(decomposition, dict)
+                  else decomposition)
+    if len({a for a, _ in pairs}) != len(pairs):
+        raise ValueError(
+            f"decomposition {pairs!r} maps some array axis more than "
+            "once; each grid axis decomposes over at most one mesh axis")
+    for a, name in pairs:
+        if not 0 <= int(a) < n_axes:
+            raise ValueError(
+                f"decomposition names array axis {a}, but fields have "
+                f"only {n_axes} grid axes")
+        if name not in mesh_axis_names:
+            raise ValueError(
+                f"mesh {tuple(mesh_axis_names)} has no axis {name!r} "
+                f"(decomposition of array axis {a})")
+        if slot_axis is not None and name == slot_axis:
+            raise ValueError(
+                f"axis {name!r} is the slot axis; a grid axis cannot "
+                "decompose over it")
+    return pairs
+
+
+def slot_field_spec(mesh, n_slots: int, shape: tuple, decomposition=(),
+                    slot_axis: str = "slot"):
+    """Spec for a slot-stacked grid field ``(n_slots, *shape)`` on a
+    slots × shards farm mesh: ``P(slot_axis, <grid axes>)``.
+
+    The two axes get different failure postures, deliberately:
+
+    * the slot axis is *guarded* — slots never interact, so a slot count
+      that does not divide over ``slot_axis`` runs replicated (correct,
+      just not parallel), same as :func:`slot_spec`;
+    * the grid axes *raise* — halo-exchange code inside the step ppermutes
+      over the decomposition's mesh axes assuming true shards, so quietly
+      replicating an indivisible grid axis would hand every device the
+      full extent while the exchange still shifts it: mis-sharding, not a
+      layout choice.
+    """
+    if slot_axis not in mesh.axis_names:
+        raise ValueError(f"mesh {mesh.axis_names} has no slot axis "
+                         f"{slot_axis!r}")
+    pairs = validate_decomposition(decomposition, len(shape),
+                                   mesh.axis_names, slot_axis=slot_axis)
+    grid: list = [None] * len(shape)
+    for a, name in pairs:
+        a = int(a)
+        if shape[a] % mesh.shape[name]:
+            raise ValueError(
+                f"grid extent {shape[a]} on array axis {a} is not "
+                f"divisible by mesh axis {name!r} (size "
+                f"{mesh.shape[name]}) — refusing to mis-shard")
+        grid[a] = name
+    return P(_slot_guard(mesh, slot_axis, n_slots), *grid)
+
+
 def plan_decomposition(config: CFDConfig, mesh,
                        slot_axis: str | None = None
                        ) -> tuple[CFDConfig, dict]:
@@ -65,7 +144,7 @@ def plan_decomposition(config: CFDConfig, mesh,
     farm) instead of threading no-op collectives through the step.
 
     Raises ``ValueError`` when a decomposition is requested without a
-    mesh, or fails ``dist.sharding.validate_decomposition`` (duplicate /
+    mesh, or fails :func:`validate_decomposition` (duplicate /
     out-of-range array axis, unknown mesh axis, decomposing over the slot
     axis).  All validation runs BEFORE the extent-1 filter, so a
     mis-assembled config fails identically on a 1-shard laptop mesh and a
@@ -79,8 +158,6 @@ def plan_decomposition(config: CFDConfig, mesh,
             "per-slot grid decomposition, which needs a farm mesh naming "
             "those axes (SimulationFarm(..., mesh=make_mesh((slots, shards), "
             "('slot', 'shard')))); got mesh=None")
-    from repro.dist.sharding import validate_decomposition
-
     pairs = validate_decomposition(config.decomposition, len(config.shape),
                                    mesh.axis_names, slot_axis=slot_axis)
     active = {a: n for a, n in pairs if mesh.shape[n] > 1}
@@ -151,11 +228,8 @@ def make_ensemble_step(solver: NavierStokes3D, *, mesh=None,
     if mesh is None:
         return jax.jit(run_k)
 
-    from repro.dist.sharding import slot_field_spec, slot_spec
-
-    # divisibility-guarded like every substrate rule: a slot count that
-    # does not divide over the axis runs replicated (correct, just not
-    # parallel) rather than erroring
+    # divisibility-guarded: a slot count that does not divide over the
+    # axis runs replicated (correct, just not parallel) rather than erroring
     n = n_slots if n_slots is not None else mesh.shape[slot_axis]
     sp = slot_spec(mesh, n, axis=slot_axis)
     decomp = dict(solver.domain.decomposition)
@@ -249,8 +323,6 @@ class EnsembleExecutor:
             # pin the resident batch to its farm layout up front: slot axis
             # over `slot_axis`, grid axes over the active decomposition —
             # admissions then scatter into place instead of re-laying-out
-            from repro.dist.sharding import slot_field_spec, slot_spec
-
             spec = (slot_field_spec(mesh, n_slots, solver_cfg.shape, decomp,
                                     slot_axis=slot_axis)
                     if decomp else slot_spec(mesh, n_slots, axis=slot_axis))
@@ -291,8 +363,6 @@ class EnsembleExecutor:
             blank = jnp.zeros((K, N_DIAG), jnp.float32).at[:, 0].set(-1.0)
             ring = jnp.broadcast_to(blank, (n_slots, K, N_DIAG))
             if mesh is not None:
-                from repro.dist.sharding import slot_spec
-
                 ring = jax.device_put(ring, NamedSharding(
                     mesh, slot_spec(mesh, n_slots, axis=slot_axis)))
             self.health_ring = ring

@@ -25,10 +25,10 @@ import jax
 
 from repro import obs
 from repro.cfd.ns3d import CFDConfig, NavierStokes3D, host_params
-from repro.serve.slots import SlotTable
 from repro.sim.ensemble import (
     EnsembleExecutor, make_ensemble_step, plan_decomposition,
 )
+from repro.sim.slots import SlotTable
 
 
 # -- compile cache -----------------------------------------------------------

@@ -1,8 +1,7 @@
 """Fixed-slot occupancy table + FIFO admission queue.
 
-The continuous-batching pattern shared by the LM serving engine
-(:mod:`repro.serve.engine`) and the CFD simulation farm
-(:mod:`repro.sim.farm`): a fixed device batch of ``n_slots`` resident
+The simulation farm's admission table (:mod:`repro.sim.farm`), in the
+continuous-batching pattern: a fixed device batch of ``n_slots`` resident
 items, a host-side FIFO of waiting work, and slot reclamation — whenever a
 slot frees, the next queued item is admitted into it and the whole batch
 keeps stepping.  The table owns only host-side bookkeeping; callers own the

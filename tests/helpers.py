@@ -8,6 +8,7 @@ with ``--xla_force_host_platform_device_count``.
 """
 from __future__ import annotations
 
+import ast
 import os
 import subprocess
 import sys
@@ -38,3 +39,31 @@ def run_with_devices(script: str, n_devices: int = 8, timeout: int = 600):
             f"--- stdout ---\n{proc.stdout}\n--- stderr ---\n{proc.stderr}"
         )
     return proc.stdout
+
+
+class MeshStub:
+    """Just (axis_names, shape) — all the spec rules ever read.
+
+    Lets one CPU assert the layout rules for any mesh geometry without
+    forcing a device count.
+    """
+
+    def __init__(self, **axes):
+        self.axis_names = tuple(axes)
+        self.shape = dict(axes)
+
+
+def imported_modules(path):
+    """Every module a Python file imports, function-level imports
+    included: ``import a.b`` yields ``a.b``; ``from a import b`` yields
+    ``a`` and ``a.b``."""
+    with open(path) as f:
+        tree = ast.parse(f.read(), filename=path)
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            for a in node.names:
+                yield a.name
+        elif isinstance(node, ast.ImportFrom) and node.module:
+            yield node.module
+            for a in node.names:      # "from repro.sim import farm"
+                yield f"{node.module}.{a.name}"

@@ -7,7 +7,6 @@ Plus: registry round-trips, schedule-bin ordering laws (hypothesis),
 residual-based convergence, priority admission, per-sim failure
 surfacing, and import hygiene for examples/ and benchmarks/.
 """
-import ast
 import dataclasses
 import os
 
@@ -21,7 +20,7 @@ from repro.cfd import cavity, taylor_green
 from repro.cfd.ns3d import NavierStokes3D
 from repro.core.schedule import BINS, Schedule, ScheduleError
 from repro.sim import SimulationFarm, SimulationService
-from tests.helpers import run_with_devices
+from tests.helpers import imported_modules, run_with_devices
 
 N = 16
 KW = dict(jacobi_iters=20)
@@ -325,19 +324,6 @@ FORBIDDEN_MODULES = ("repro.sim.ensemble", "repro.sim.farm",
                      "repro.sim.service", "repro.core.driver")
 
 
-def _imported_modules(path):
-    with open(path) as f:
-        tree = ast.parse(f.read(), filename=path)
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Import):
-            for a in node.names:
-                yield a.name
-        elif isinstance(node, ast.ImportFrom) and node.module:
-            yield node.module
-            for a in node.names:      # "from repro.sim import farm"
-                yield f"{node.module}.{a.name}"
-
-
 def test_examples_and_benchmarks_import_through_the_api():
     """The front door is the only supported path into the farm/driver
     internals: examples and benchmarks must not reach around it."""
@@ -347,7 +333,7 @@ def test_examples_and_benchmarks_import_through_the_api():
         for fname in sorted(os.listdir(os.path.join(root, d))):
             if not fname.endswith(".py"):
                 continue
-            for mod in _imported_modules(os.path.join(root, d, fname)):
+            for mod in imported_modules(os.path.join(root, d, fname)):
                 if mod in FORBIDDEN_MODULES:
                     offenders.append(f"{d}/{fname} imports {mod}")
     assert not offenders, (

@@ -24,18 +24,7 @@ from repro.dist.compression import (
     dequantize_int8, ef_allreduce_mean, quantize_int8, wire_bytes,
 )
 from repro.models import model
-
-
-class _MeshStub:
-    """Just (axis_names, shape) — all the spec builders ever read.
-
-    Lets one CPU assert the layout rules for any mesh geometry without
-    forcing a device count.
-    """
-
-    def __init__(self, **axes):
-        self.axis_names = tuple(axes)
-        self.shape = dict(axes)
+from tests.helpers import MeshStub
 
 
 def _llama_specs(mesh, global_batch=8):
@@ -50,7 +39,7 @@ def _llama_specs(mesh, global_batch=8):
 # sharding rules (mesh-geometry sweep, no devices needed)
 # ---------------------------------------------------------------------------
 def test_param_specs_fsdp_tp_layout():
-    mesh = _MeshStub(data=2, model=4)
+    mesh = MeshStub(data=2, model=4)
     _, _, specs = _llama_specs(mesh)
     stack = specs["stack"]["layers"]
     assert stack["attn"]["wq"] == P(None, "data", "model", None)
@@ -65,7 +54,7 @@ def test_param_specs_fsdp_tp_layout():
 
 def test_param_specs_divisibility_guard_wide_tp():
     """kv_heads=8 over model=16: the guard replicates instead of erroring."""
-    mesh = _MeshStub(data=2, model=16)
+    mesh = MeshStub(data=2, model=16)
     _, _, specs = _llama_specs(mesh)
     stack = specs["stack"]["layers"]
     assert stack["attn"]["wk"] == P(None, "data", None, None)   # 8 % 16 != 0
@@ -74,7 +63,7 @@ def test_param_specs_divisibility_guard_wide_tp():
 
 def test_cache_specs_seq_guard():
     cfg = get_config("llama3-8b")
-    mesh = _MeshStub(data=2, model=4)
+    mesh = MeshStub(data=2, model=4)
     shard = shd.make_shard_cfg(mesh, cfg, global_batch=8)
     mk = lambda s: jax.eval_shape(
         lambda: model.init_caches(cfg, 8, s, jnp.bfloat16))
@@ -87,7 +76,7 @@ def test_cache_specs_seq_guard():
 
 def test_batch_specs_and_non_divisible_batch():
     cfg = get_config("llama3-8b")
-    mesh = _MeshStub(data=4, model=2)
+    mesh = MeshStub(data=4, model=2)
     batch = {"tokens": jax.ShapeDtypeStruct((8, 64), jnp.int32)}
     shard = shd.make_shard_cfg(mesh, cfg, global_batch=8)
     assert shard.batch_sharded
@@ -101,7 +90,7 @@ def test_batch_specs_and_non_divisible_batch():
 
 def test_make_shard_cfg_modes():
     cfg = get_config("llama3-8b")
-    mesh = _MeshStub(pod=2, data=2, model=2)
+    mesh = MeshStub(pod=2, data=2, model=2)
     fsdp = shd.make_shard_cfg(mesh, cfg, global_batch=8)
     assert fsdp.dp == ("pod", "data") and fsdp.tp == "model"
     assert not fsdp.replicate_params
@@ -119,7 +108,7 @@ def test_make_shard_cfg_modes():
 
 def test_moe_and_ssm_spec_trees_cover_all_leaves():
     """Every family's tree gets a spec per leaf (structure mirrors)."""
-    mesh = _MeshStub(data=2, model=4)
+    mesh = MeshStub(data=2, model=4)
     for arch in ("qwen3-moe-235b-a22b", "zamba2-1.2b", "xlstm-125m"):
         cfg = get_config(arch)
         shard = shd.make_shard_cfg(mesh, cfg, global_batch=8)
@@ -140,7 +129,7 @@ def test_moe_and_ssm_spec_trees_cover_all_leaves():
 
 
 def test_moe_experts_are_expert_parallel():
-    mesh = _MeshStub(data=2, model=4)
+    mesh = MeshStub(data=2, model=4)
     cfg = get_config("qwen3-moe-235b-a22b")
     shard = shd.make_shard_cfg(mesh, cfg, global_batch=8)
     shapes = jax.eval_shape(
@@ -178,106 +167,6 @@ def test_path_str_matches_decay_filter_contract():
     f = AdamW().decay_filter
     decayed = {p for p in paths if f(p)}
     assert decayed == {"stack/layers/ffn/down/w", "embed/table"}
-
-
-def test_slot_spec():
-    mesh = _MeshStub(data=4, model=2)
-    assert shd.slot_spec(mesh, 8) == P("data")
-    assert shd.slot_spec(mesh, 6) == P(None)        # 6 % 4 != 0 -> replicated
-
-
-# ---------------------------------------------------------------------------
-# slots x shards field specs (the 2-axis farm mesh)
-# ---------------------------------------------------------------------------
-def test_slot_field_spec_slot_times_shard():
-    mesh = _MeshStub(slot=2, shard=4)
-    spec = shd.slot_field_spec(mesh, 8, (16, 16, 4), ((0, "shard"),))
-    assert spec == P("slot", "shard", None, None)
-
-
-def test_slot_field_spec_two_axis_grid_decomposition():
-    mesh = _MeshStub(slot=2, sx=2, sy=2)
-    spec = shd.slot_field_spec(mesh, 4, (16, 16, 8), ((0, "sx"), (1, "sy")))
-    assert spec == P("slot", "sx", "sy", None)
-
-
-def test_slot_field_spec_undecomposed_grid():
-    mesh = _MeshStub(slot=4)
-    assert shd.slot_field_spec(mesh, 8, (16, 16, 4)) == \
-        P("slot", None, None, None)
-
-
-def test_slot_field_spec_indivisible_slots_replicate():
-    """Slots never interact -> the slot axis is guarded, not an error."""
-    mesh = _MeshStub(slot=2, shard=4)
-    spec = shd.slot_field_spec(mesh, 3, (16, 16, 4), ((0, "shard"),))
-    assert spec == P(None, "shard", None, None)
-
-
-def test_slot_field_spec_indivisible_grid_raises():
-    """Grid axes RAISE: halo code ppermutes assuming true shards, so a
-    silently replicated axis would be mis-sharded, not just unparallel."""
-    mesh = _MeshStub(slot=2, shard=4)
-    with pytest.raises(ValueError, match="not divisible"):
-        shd.slot_field_spec(mesh, 8, (10, 16, 4), ((0, "shard"),))
-
-
-def test_slot_field_spec_unknown_axes_raise():
-    mesh = _MeshStub(slot=2, shard=4)
-    with pytest.raises(ValueError, match="no slot axis"):
-        shd.slot_field_spec(mesh, 8, (16, 16, 4), ((0, "shard"),),
-                            slot_axis="slots")
-    with pytest.raises(ValueError, match="no axis 'model'"):
-        shd.slot_field_spec(mesh, 8, (16, 16, 4), ((0, "model"),))
-    with pytest.raises(ValueError, match="slot axis"):
-        shd.slot_field_spec(mesh, 8, (16, 16, 4), ((0, "slot"),))
-
-
-def test_slot_field_spec_bad_array_axis_raises():
-    mesh = _MeshStub(slot=2, shard=4)
-    with pytest.raises(ValueError, match="array axis 3"):
-        shd.slot_field_spec(mesh, 8, (16, 16, 4), ((3, "shard"),))
-
-
-def test_slot_field_spec_duplicate_array_axis_raises():
-    """One grid axis mapped twice must raise, not silently keep the last
-    mapping (dict() would dedup to half the requested parallelism)."""
-    mesh = _MeshStub(slot=2, sx=2, sy=2)
-    with pytest.raises(ValueError, match="more than once"):
-        shd.slot_field_spec(mesh, 8, (16, 16, 4), ((0, "sx"), (0, "sy")))
-
-
-def test_slot_field_spec_covers_eval_shape_state():
-    """The rule applied over a real solver state tree (eval_shape — no
-    arrays, no devices): every field of the slot-stacked ensemble state
-    gets the same P(slot, shard, ...) placement."""
-    from repro.cfd import cavity
-    from repro.cfd.ns3d import NavierStokes3D
-
-    solver = NavierStokes3D(cavity.config(16, jacobi_iters=20))
-    shapes = jax.eval_shape(solver.init_state)
-    mesh = _MeshStub(slot=2, shard=4)
-    specs = {k: shd.slot_field_spec(mesh, 8, v.shape, ((0, "shard"),))
-             for k, v in shapes.items()}
-    assert set(specs) >= {"vx", "vy", "vz", "p"}
-    for k, spec in specs.items():
-        assert spec == P("slot", "shard", None, None), k
-
-
-def test_slot_field_spec_matches_solver_field_pspec():
-    """dist's slot-stacked spec == P(slot, *solver.field_pspec): the two
-    layers agree on the grid placement by construction."""
-    from repro.cfd import cavity
-    from repro.cfd.ns3d import NavierStokes3D
-    from repro.launch.mesh import make_mesh
-
-    mesh = make_mesh((1, 1), ("slot", "shard"))
-    solver = NavierStokes3D(
-        cavity.config(16, jacobi_iters=20, decomposition=((0, "shard"),)),
-        mesh)
-    stacked = shd.slot_field_spec(mesh, 4, solver.config.shape,
-                                  solver.config.decomposition)
-    assert tuple(stacked)[1:] == tuple(solver.field_pspec)
 
 
 # ---------------------------------------------------------------------------
@@ -459,25 +348,3 @@ def test_exchange_pad_width_beyond_extent_raises_property(n, extra, bc):
     w = n + extra
     with pytest.raises(ValueError, match="halo width"):
         exchange_pad(u, (w, w, w), _specs(bc))
-
-
-@settings(max_examples=25)
-@given(n=st.integers(5, 64), shards=st.integers(2, 8),
-       slots=st.integers(1, 8))
-def test_indivisible_grid_shard_combinations_raise_property(n, shards, slots):
-    """Every layer that could mis-shard an indivisible grid refuses
-    instead: the spec rule raises, and the driver's Domain validation
-    raises — never a silently replicated 'shard'."""
-    if n % shards == 0:
-        n += 1                      # force indivisibility
-        if n % shards == 0:         # (can't happen, but keep it obvious)
-            return
-    mesh = _MeshStub(slot=2, shard=shards)
-    with pytest.raises(ValueError, match="not divisible"):
-        shd.slot_field_spec(mesh, slots, (n, 16, 4), ((0, "shard"),))
-
-    from repro.core.driver import Domain, GridDriver
-
-    with pytest.raises(ValueError, match="not divisible"):
-        GridDriver(Domain(shape=(n, 16, 4), decomposition={0: "shard"}),
-                   mesh)

@@ -7,7 +7,6 @@ import jax.numpy as jnp
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
-from repro.kernels.attention import flash_attention
 from repro.kernels.jacobi import jacobi_fused, jacobi_fused_ref
 
 
@@ -146,53 +145,3 @@ class TestJacobi:
             p = p - p.mean()
         assert residual(p) < 0.05 * r0
 
-
-class TestFlashAttention:
-    @settings(max_examples=10, deadline=None)
-    @given(
-        h=st.sampled_from([1, 2, 4]),
-        rep=st.sampled_from([1, 2]),
-        s=st.sampled_from([128, 256]),
-        d=st.sampled_from([32, 64]),
-        causal=st.booleans(),
-        dtype=st.sampled_from([np.float32]),
-    )
-    def test_property_matches_reference(self, h, rep, s, d, causal, dtype):
-        hq = h * rep
-        rng = np.random.RandomState(h * 100 + s)
-        q = jnp.asarray(rng.randn(hq, s, d).astype(dtype) * 0.3)
-        k = jnp.asarray(rng.randn(h, s, d).astype(dtype) * 0.3)
-        v = jnp.asarray(rng.randn(h, s, d).astype(dtype))
-        got = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64,
-                              interpret=True)
-        want = ref.mha_reference(q.transpose(1, 0, 2), k.transpose(1, 0, 2),
-                                 v.transpose(1, 0, 2), causal=causal)
-        np.testing.assert_allclose(np.asarray(got),
-                                   np.asarray(want.transpose(1, 0, 2)),
-                                   rtol=2e-5, atol=2e-5)
-
-    def test_bf16_io(self):
-        rng = np.random.RandomState(0)
-        q = jnp.asarray(rng.randn(2, 128, 64), dtype=jnp.bfloat16)
-        k = jnp.asarray(rng.randn(2, 128, 64), dtype=jnp.bfloat16)
-        v = jnp.asarray(rng.randn(2, 128, 64), dtype=jnp.bfloat16)
-        got = flash_attention(q, k, v, interpret=True)
-        want = ref.mha_reference(q.transpose(1, 0, 2), k.transpose(1, 0, 2),
-                                 v.transpose(1, 0, 2)).transpose(1, 0, 2)
-        assert got.dtype == jnp.bfloat16
-        np.testing.assert_allclose(np.asarray(got, np.float32),
-                                   np.asarray(want, np.float32),
-                                   rtol=3e-2, atol=3e-2)
-
-    def test_decode_offset(self):
-        rng = np.random.RandomState(1)
-        q = jnp.asarray(rng.randn(2, 64, 32).astype(np.float32))
-        k = jnp.asarray(rng.randn(2, 256, 32).astype(np.float32))
-        v = jnp.asarray(rng.randn(2, 256, 32).astype(np.float32))
-        got = flash_attention(q, k, v, causal=True, q_offset=192,
-                              block_q=64, block_k=64, interpret=True)
-        want = ref.mha_reference(q.transpose(1, 0, 2), k.transpose(1, 0, 2),
-                                 v.transpose(1, 0, 2), causal=True,
-                                 q_offset=192).transpose(1, 0, 2)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)

@@ -7,8 +7,8 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.kernels.ssd import ssd_intra_pallas, ssd_intra_reference
 from repro.models.mamba2 import ssd_chunked, ssd_core
+from repro.models.ssd import ssd_intra_pallas, ssd_intra_reference
 
 
 def _inputs(key, bsz, nc, l, g, r, n, p, dtype=jnp.float32):

@@ -906,7 +906,7 @@ print("PALLAS SLOT-SHARD OK")
 class TestMultiDeviceFarm:
     def test_sharded_farm_matches_single_device(self):
         """Slot axis over a data-parallel mesh axis (vmap x shard_map via
-        dist.sharding.slot_spec): the distributed farm must reproduce the
+        sim.ensemble.slot_spec): the distributed farm must reproduce the
         single-device farm bitwise — slots never interact, so placement
         is pure bookkeeping."""
         from tests.helpers import run_with_devices

@@ -278,10 +278,11 @@ class Runtime:
         tel = self.telemetry if self.telemetry.enabled else None
         state = sched.compile_bin("INITIAL", telemetry=tel)({})
         evolve = sched.compile_bin("EVOLVE", telemetry=tel)
-        # a decomposed step's halo traffic per device, and the sweeps
-        # that still build a padded copy of p (a stat only where there
-        # are any: 3DBLOCK, the fused smoother), counted once here from
-        # the plan and carried by every step's span as its stats
+        # a decomposed step's halo traffic per device, the sweeps that
+        # still build a padded copy of p (a stat only where there are
+        # any: 3DBLOCK, the fused smoother) and those that stream p
+        # through VMEM (only on a TPU), counted once here from the plan
+        # and carried by every step's span as its stats
         from repro.obs import perf
 
         stats = {}
@@ -294,6 +295,11 @@ class Runtime:
         padded = perf.padded_sweeps_per_step(solver_cfg)
         if padded:
             stats["padded_sweeps"] = padded
+        device = (self.mesh.devices.flat[0] if active else jax.devices()[0])
+        streamed = perf.streamed_sweeps_per_step(
+            solver_cfg, solver.driver.local_shape, device.platform)
+        if streamed:
+            stats["streamed_sweeps"] = streamed
 
         def step(st: dict) -> dict:
             with obs.span("runtime.step", **stats):

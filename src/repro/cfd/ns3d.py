@@ -27,10 +27,11 @@ import numpy as np
 from jax import lax
 
 from repro.core import AxisSpec, Domain, GridDriver, bc_dirichlet, bc_neumann
-from repro.core.halo import (exchange_pad, shifted_neighbours,
+from repro.core.halo import (exchange_pad, ghost_faces, shifted_neighbours,
                              stencil_step_overlap)
 from repro.kernels import ops, ref
-from repro.kernels.jacobi import jacobi_fused_ref, jacobi_pressure_unpadded
+from repro.kernels.jacobi import (jacobi_fused_ref, jacobi_pressure_streamed,
+                                  jacobi_pressure_unpadded, streams)
 
 
 def bc_moving_wall(u_wall: float):
@@ -290,14 +291,34 @@ class NavierStokes3D:
         # -- 3. pressure Poisson (warm start from previous p)
         p_specs = specs("p")
         k = c.fused_sweeps
+        streamed = (k <= 1 and kw["template"] == "JNP"
+                    and streams(p.shape, p_specs))
+
+        def unpadded(pcur):
+            # the seven-point sweep reads no corners: p's six face
+            # neighbours at its own shape, no padded copy of p
+            return jacobi_pressure_unpadded(
+                pcur, shifted_neighbours(pcur, p_specs), rhs, h=h,
+                omega=c.jacobi_omega)
+
+        def on_tpu(pcur):
+            # x's outer planes: p's own on a whole periodic x, else the
+            # ghost faces (a ppermute on a split x)
+            x = p_specs[0]
+            faces = (None if x.periodic and x.mesh_axis is None
+                     else ghost_faces(pcur, x))
+            return jacobi_pressure_streamed(pcur, rhs, faces, h=h,
+                                            omega=c.jacobi_omega,
+                                            interpret=c.interpret)
 
         def jacobi_body(_, pcur):
+            if streamed:
+                # a p larger than XLA stages on chip, in lane-aligned
+                # periodic planes: streamed through VMEM on a TPU
+                return lax.platform_dependent(pcur, tpu=on_tpu,
+                                              default=unpadded)
             if k <= 1 and kw["template"] == "JNP":
-                # the seven-point sweep reads no corners: p's six face
-                # neighbours at its own shape, no padded copy of p
-                return jacobi_pressure_unpadded(
-                    pcur, shifted_neighbours(pcur, p_specs), rhs, h=h,
-                    omega=c.jacobi_omega)
+                return unpadded(pcur)
             if k <= 1:
                 pp = exchange_pad(pcur, (1, 1, 1), p_specs)
                 return ops.jacobi_pressure(pp, rhs, h=h, omega=c.jacobi_omega, **kw)

@@ -174,6 +174,16 @@ def exchange_pad(
     return u
 
 
+def ghost_faces(u: jnp.ndarray, spec: AxisSpec
+                ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The one-wide ghosts past ``u``'s two ends along ``spec``'s axis,
+    as :func:`exchange_pad` fills them (:func:`_ghosts`), under its
+    ``exchange_pad`` scope: a kernel that reads ``u``'s interior itself
+    takes these as its outer planes."""
+    with jax.named_scope("exchange_pad"):
+        return _ghosts(u, 1, 1, spec)
+
+
 def _shift(u: jnp.ndarray, axis: int, k: int) -> jnp.ndarray:
     """``v[i] = u[i - k]`` along ``axis`` where that row exists, else 0."""
     cfg = [(0, 0, 0)] * u.ndim

@@ -161,6 +161,27 @@ def padded_sweeps_per_step(config) -> int:
     return max(config.jacobi_iters // k, 1)
 
 
+def streamed_sweeps_per_step(config, local_shape, platform: str) -> int:
+    """Jacobi sweeps of ONE ns3d step that stream ``p`` through VMEM
+    (``kernels.jacobi.jacobi_pressure_streamed``): every loop trip of the
+    jnp template's single sweeps on a TPU, where ``p`` of ``local_shape``
+    outgrows what XLA stages on chip, its planes are whole (8, 128) tiles
+    and y and z are periodic on no mesh axis (``jacobi.streams``); none
+    elsewhere."""
+    from repro.cfd.ns3d import PERIODIC_CASES
+    from repro.core.halo import AxisSpec
+    from repro.kernels.jacobi import streams
+
+    if platform != "tpu" or config.fused_sweeps > 1 or \
+            (config.template or "JNP") != "JNP":
+        return 0
+    # the solver's domain: z always periodic, x and y in periodic cases
+    periodic = config.case in PERIODIC_CASES
+    split = dict(config.decomposition)
+    specs = [AxisSpec(a, split.get(a), periodic or a == 2) for a in range(3)]
+    return max(config.jacobi_iters, 1) if streams(local_shape, specs) else 0
+
+
 def _step_exchanges(config) -> list[tuple[tuple, int, bool]]:
     """``(widths, calls, padded)`` of the ghost fills of ONE ns3d step, in
     the order of ``NavierStokes3D._step_local``: three velocity fields at

@@ -21,7 +21,8 @@ from repro.cfd import cavity, taylor_green
 from repro.cfd.ns3d import PARAM_KEYS, NavierStokes3D, params_from_config
 from repro.core import autotune
 from repro.core.rooflinemodel import V5E
-from repro.kernels import ops, stencil3d
+from repro.kernels import jacobi, ops, stencil3d
+from repro.obs import perf
 
 
 @pytest.fixture(scope="module")
@@ -179,3 +180,88 @@ def test_slot_io_programs_compile_in_place(one_chip):
             k: jax.ShapeDtypeStruct((b,) + v.shape[1:], v.dtype,
                                     sharding=one_chip)
             for k, v in state.items()}).compile()
+
+
+def _jacobi_loop_bodies(hlo: str) -> dict[str, list[str]]:
+    """The instruction lines of each compiled Jacobi loop's body."""
+    bodies = {re.search(r"body=%([\w.\-]+)", line).group(1): []
+              for line in hlo.splitlines()
+              if " while(" in line and "/jacobi/" in line}
+    current = None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            current = head.group(1)
+        elif current in bodies and " = " in line:
+            bodies[current].append(line)
+    assert bodies, "no Jacobi while loop in the compiled step"
+    return bodies
+
+
+def _assert_sweeps_stream(compiled, local_shape, streamed: bool):
+    """Each Jacobi loop runs the streamed kernel (one Mosaic custom call)
+    or none, and copies no p-sized array when it does."""
+    p = "f32[" + ",".join(map(str, local_shape)) + "]"
+    for body in _jacobi_loop_bodies(compiled.as_text()).values():
+        kernels = [ln for ln in body if '"tpu_custom_call"' in ln]
+        assert len(kernels) == int(streamed), kernels
+        if streamed:
+            copies = [ln for ln in body if re.search(
+                r" = \S*" + re.escape(p) + r"\S* copy(-start)?\(", ln)]
+            assert not copies, copies
+
+
+@pytest.mark.parametrize("config, staged_bytes, streamed", [
+    pytest.param(lambda: taylor_green.config(256, nz=256), None, False,
+                 id="taylor_green-256^3"),
+    pytest.param(lambda: taylor_green.config(256, nz=256), 0, True,
+                 id="taylor_green-256^3-streamed"),
+    pytest.param(lambda: cavity.config(256), 0, False,
+                 id="cavity-256x256x4"),
+])
+def test_jnp_step_streams_lane_aligned_periodic_sweeps(
+        one_chip, monkeypatch, config, staged_bytes, streamed):
+    """The streamed kernel lowers through Mosaic in the 256^3 step where
+    the counter says it runs: not for the 64 MiB p that XLA stages on
+    chip itself, but once the size gate is lowered; the walled cavity
+    keeps the jnp sweep either way."""
+    if staged_bytes is not None:
+        monkeypatch.setattr(jacobi, "_STAGED_BYTES", staged_bytes)
+    solver = NavierStokes3D(config())
+    state = _shapes(jax.eval_shape(solver.init_state), one_chip)
+    params = {k: jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+              for k in PARAM_KEYS}
+    compiled = jax.jit(solver._step_local).lower(state, params).compile()
+    shape = solver.config.shape
+    assert bool(perf.streamed_sweeps_per_step(solver.config, shape,
+                                              "tpu")) == streamed
+    _assert_sweeps_stream(compiled, shape, streamed)
+
+
+def test_x_split_768_sweeps_stream_with_the_same_halo(topo):
+    """768^3 split in x over the described 2x2: each sweep is the
+    streamed kernel fed the two permuted x faces, with no p-sized copy in
+    the loop, and the step ships the bytes the halo counter says."""
+    from repro.launch import hlo_cost
+
+    mesh = Mesh(topo.devices[:4], ("shard",))
+    cfg = dataclasses.replace(taylor_green.config(768, nz=768),
+                              template="JNP", decomposition=((0, "shard"),))
+    solver = NavierStokes3D(cfg, mesh)
+    example = jax.eval_shape(solver.init_state)
+    params = params_from_config(cfg)
+    scalars = {k: jax.ShapeDtypeStruct((), jnp.float32,
+                                       sharding=NamedSharding(mesh, P()))
+               for k in params}
+    step = solver.driver.sharded_step_tree(solver._step_local, example,
+                                           params)
+    compiled = step.lower(_shapes(example, NamedSharding(mesh, P("shard"))),
+                          scalars).compile()
+    local = (192, 768, 768)
+    assert perf.streamed_sweeps_per_step(cfg, local, "tpu") == 60
+    _assert_sweeps_stream(compiled, local, True)
+    cost, status, _ = hlo_cost.safe_analyze(compiled.as_text(), 4)
+    assert status == "ok"
+    assert cost.collective_bytes["collective-permute"] == \
+        perf.halo_bytes_per_step(cfg, {0: "shard"}, {"shard": 4}) == \
+        306_708_480

@@ -5,15 +5,34 @@ framework's descriptor-generated kernels + driver-managed halo exchange:
 
   1. UPDATE_VELOCITY   u* = u + dt (-adv + nu lap + f)         [stencil kernel]
   2. wall masks        enforce zero wall-normal faces
+     ADVECT_DIFFUSE_SCALAR  T' = T + dt (kappa lap T - div(u T))  [stencil
+                       kernel; only in cases that carry T]
   3. DIVERGENCE        rhs = div(u*)/dt                        [stencil kernel]
   4. JACOBI_PRESSURE   iterate lap p = rhs                     [stencil kernel]
                        (optionally the fused communication-avoiding smoother)
   5. PROJECT_VELOCITY  u = u* - dt grad p                      [stencil kernel]
 
 Grid convention (see kernels/stencil3d.py): vx[i] at the right x-face of
-cell i; the hi wall face is vx[N-1].  Cases: ``cavity`` (lid-driven, lid at
-y-hi moving in +x; z periodic so the Ghia 2D profile is recovered) and
-``taylor_green`` (triply periodic, analytic solution).
+cell i; the hi wall face is vx[N-1].  Cases, with the axes each keeps
+periodic in ``PERIODIC``: ``cavity`` (lid-driven, lid at y-hi moving in
++x; z periodic so the Ghia 2D profile is recovered), ``taylor_green`` and
+``kelvin_helmholtz`` (triply periodic), and ``rayleigh_benard``.
+
+``rayleigh_benard`` is Boussinesq convection between two rigid,
+isothermal plates, in free-fall units (layer depth H = 1, plate
+temperature difference 1), x vertical and gravity along -x:
+
+  du/dt + div(u u) = -grad p + nu lap u + T e_x,    div u = 0,
+  dT/dt + div(u T) = kappa lap T,
+  nu = sqrt(Pr / Ra),  kappa = 1 / sqrt(Ra Pr).
+
+The plate at x = 0 is hot (T = +1/2), the one at x = 1 cold (T = -1/2);
+both are no-slip and impenetrable; y and z are periodic.  T lives at cell
+centres and is the state's fifth field: ADVECT_DIFFUSE_SCALAR advances
+it with the velocity of the step's start (explicit Euler, face-mean fluxes,
+7-point diffusion; its ghost across a plate is 2 T_plate - the adjacent
+row), and vx on the x-face between cells i and i+1 takes the buoyancy
+(T_i + T_{i+1}) / 2 in UPDATE_VELOCITY.
 """
 from __future__ import annotations
 
@@ -50,7 +69,8 @@ class CFDConfig:
     extent: float = 1.0                      # cubic cells: h = extent/shape[0]
     nu: float = 0.01
     dt: float = 2.5e-3
-    case: str = "cavity"                     # "cavity" | "taylor_green"
+    case: str = "cavity"                     # a key of PERIODIC
+    kappa: float = 0.0                       # scalar diffusivity (T cases)
     lid_velocity: float = 1.0
     forcing: tuple[float, float, float] = (0.0, 0.0, 0.0)
     jacobi_iters: int = 40
@@ -75,7 +95,7 @@ class CFDConfig:
 # ensemble members sharing one compiled step.  Grid geometry (shape, h) and
 # solver structure (iterations, overlap, template) stay static — they select
 # the compiled executable; these select the physics, as traced f32 scalars.
-PARAM_KEYS = ("nu", "dt", "lid_velocity", "fx", "fy", "fz")
+PARAM_KEYS = ("nu", "dt", "lid_velocity", "fx", "fy", "fz", "kappa")
 
 
 def params_from_config(c: CFDConfig) -> dict:
@@ -93,15 +113,33 @@ def host_params(c: CFDConfig) -> dict:
     what the farm installs at admission, with no device round trip."""
     fx, fy, fz = c.forcing
     vals = dict(nu=c.nu, dt=c.dt, lid_velocity=c.lid_velocity,
-                fx=fx, fy=fy, fz=fz)
+                fx=fx, fy=fy, fz=fz, kappa=c.kappa)
     return {k: np.float32(vals[k]) for k in PARAM_KEYS}
 
 
-# Cases whose domain is fully periodic (no wall BCs, no wall masks).
-# "kelvin_helmholtz" shares the solver structure of "taylor_green" — its
-# shear-layer initial condition is owned by the scenario registry
+# Which axes of each case are periodic; every other axis has a wall at
+# both ends.  The domain, the BC table, the wall masks and obs.perf read
+# it.  "kelvin_helmholtz" shares the solver structure of "taylor_green" —
+# its shear-layer initial condition is owned by the scenario registry
 # (repro.sim.scenarios), not by the solver.
-PERIODIC_CASES = ("taylor_green", "kelvin_helmholtz")
+PERIODIC = {
+    "cavity": (False, False, True),
+    "taylor_green": (True, True, True),
+    "kelvin_helmholtz": (True, True, True),
+    "rayleigh_benard": (False, True, True),
+}
+# Cases that carry the temperature T, and its plates' values (lo, hi in x)
+SCALAR_CASES = ("rayleigh_benard",)
+PLATE_T = (0.5, -0.5)
+
+
+def periodic_axes(case: str) -> tuple[bool, bool, bool]:
+    """``PERIODIC[case]``, or ValueError naming the known cases."""
+    try:
+        return PERIODIC[case]
+    except KeyError:
+        raise ValueError(f"unknown case {case!r}; known: "
+                         f"{sorted(PERIODIC)}") from None
 
 # Physics columns of one in-situ health frame, in the order
 # ``health_diagnostics`` stacks them.  ``obs.health.DIAG_COLUMNS`` is
@@ -111,18 +149,25 @@ HEALTH_DIAGS = ("div_linf", "ke", "umax", "cfl", "finite")
 
 
 class NavierStokes3D:
-    """The CFD application object: owns the driver, BCs, and the step."""
+    """The CFD application object: owns the driver, BCs, and the step.
+
+    ``FIELDS`` are the fields the step writes: the flow's four, and on an
+    instance whose case carries a temperature (``SCALAR_CASES``) ``"T"``
+    after them.
+    """
 
     FIELDS = ("vx", "vy", "vz", "p")
 
     def __init__(self, config: CFDConfig, mesh: jax.sharding.Mesh | None = None):
         self.config = config
-        periodic = config.case in PERIODIC_CASES
+        self.scalar = config.case in SCALAR_CASES
+        if self.scalar:
+            self.FIELDS = NavierStokes3D.FIELDS + ("T",)
         self.domain = Domain(
             shape=config.shape,
             spacing=(config.h,) * 3,
             decomposition=dict(config.decomposition),
-            periodic=(periodic, periodic, True),
+            periodic=periodic_axes(config.case),
         )
         self.driver = GridDriver(self.domain, mesh)
         self._health_jit = None   # lazy fused health_report executable
@@ -141,26 +186,32 @@ class NavierStokes3D:
 
     # ------------------------------------------------------------------ BCs
     def _bcs_for(self, lid_velocity) -> dict:
-        """BC rule table; ``lid_velocity`` may be a traced per-slot scalar."""
-        c = self.config
-        if c.case in PERIODIC_CASES:
-            # fully periodic: no BC rules needed anywhere
-            return {f: ((None,) * 3, (None,) * 3) for f in self.FIELDS}
+        """BC rule table, ``{field: (bc_lo per axis, bc_hi per axis)}``,
+        with a rule on each walled axis and None on each periodic one;
+        ``lid_velocity`` may be a traced per-slot scalar."""
+        walls = [not p for p in self.domain.periodic]
         noslip = bc_moving_wall(0.0)
-        lid = bc_moving_wall(lid_velocity)
         zero = bc_dirichlet(0.0)
         neum = bc_neumann()
-        # (bc_lo per axis, bc_hi per axis); z is periodic via Domain.periodic
-        return {
-            # vx: normal to x walls (ghost faces 0), tangential in y (lid at hi)
-            "vx": ((zero, noslip, None), (zero, lid, None)),
-            # vy: tangential in x, normal to y walls
-            "vy": ((noslip, zero, None), (noslip, zero, None)),
-            # vz: tangential to x and y walls
-            "vz": ((noslip, noslip, None), (noslip, noslip, None)),
-            # p: homogeneous Neumann at all walls
-            "p": ((neum, neum, None), (neum, neum, None)),
-        }
+
+        def on_walls(rule_of_axis):
+            return [rule_of_axis(a) if walls[a] else None for a in range(3)]
+
+        table = {}
+        for n, f in enumerate(("vx", "vy", "vz")):
+            # a face velocity's ghost is 0 across the walls it is normal
+            # to, and no-slip across those it is tangential to
+            rules = on_walls(lambda a: zero if a == n else noslip)
+            table[f] = [rules, list(rules)]
+        if self.config.case == "cavity":
+            # the lid: the y-hi wall moving in +x
+            table["vx"][1][1] = bc_moving_wall(lid_velocity)
+        # p: homogeneous Neumann at all walls
+        table["p"] = [on_walls(lambda a: neum)] * 2
+        if self.scalar:
+            # T: the plates' temperatures across the x walls
+            table["T"] = [[bc_moving_wall(t), None, None] for t in PLATE_T]
+        return {f: (tuple(lo), tuple(hi)) for f, (lo, hi) in table.items()}
 
     def _build_bcs(self):
         self.bc = self._bcs_for(self.config.lid_velocity)
@@ -184,20 +235,20 @@ class NavierStokes3D:
         return state
 
     def _masks(self):
-        """Zero the wall-normal boundary faces (vx[N-1] on x, etc.)."""
-        c = self.config
+        """Zero the wall-normal boundary faces (vx[N-1] on a walled x,
+        etc.); a periodic axis masks nothing."""
         sh = self.driver.sharding()
-        ones = np.ones(c.shape, np.float32)
-        mx, my, mz = ones.copy(), ones.copy(), ones.copy()
-        if c.case not in PERIODIC_CASES:
-            mx[-1, :, :] = 0.0
-            my[:, -1, :] = 0.0
-            # z periodic: no vz mask
+        masks = []
+        for a, periodic in enumerate(self.domain.periodic):
+            m = np.ones(self.config.shape, np.float32)
+            if not periodic:
+                m[(slice(None),) * a + (-1,)] = 0.0
+            masks.append(m)
         # host -> shards directly: staging through jnp.asarray would put
         # the whole grid on the default device first
         if sh is not None:
-            return [jax.device_put(m, sh) for m in (mx, my, mz)]
-        return [jnp.asarray(m) for m in (mx, my, mz)]
+            return [jax.device_put(m, sh) for m in masks]
+        return [jnp.asarray(m) for m in masks]
 
     # ----------------------------------------------------------------- step
     def _global_mean(self, x):
@@ -229,10 +280,11 @@ class NavierStokes3D:
         serial decomposed run.
 
         Each stage runs in a ``jax.named_scope`` (``update_velocity``,
-        ``divergence``, ``jacobi``, ``project``; the ghost fills are
-        ``exchange_pad``), so every op of the compiled step carries its
-        stage in its metadata, serial, shard-mapped or vmapped: a device
-        profile splits the step's time by stage.
+        ``update_temperature`` where the case carries T, ``divergence``,
+        ``jacobi``, ``project``; the ghost fills are ``exchange_pad``), so
+        every op of the compiled step carries its stage in its metadata,
+        serial, shard-mapped or vmapped: a device profile splits the
+        step's time by stage.
         """
         c = self.config
         if params is None:
@@ -280,7 +332,23 @@ class NavierStokes3D:
                         for f, v in (("vx", vx), ("vy", vy), ("vz", vz))]
                 vx_s, vy_s, vz_s = ops.update_velocity(*pads, **vel_params,
                                                        **kw)
+            if self.scalar:
+                # buoyancy: vx on the face between cells i and i+1 takes
+                # their mean temperature, a per-face forcing that fuses
+                # with the mask into the stage's last pass over vx
+                tp = exchange_pad(state["T"], (1, 1, 1), specs("T"))
+                vx_s = vx_s + dt * (0.5 * (tp[1:-1, 1:-1, 1:-1]
+                                           + tp[2:, 1:-1, 1:-1]))
             vx_s, vy_s, vz_s = vx_s * mvx, vy_s * mvy, vz_s * mvz
+
+        out = {}
+        if self.scalar:
+            # -- 1b. the temperature, advected by the step's first velocity
+            with jax.named_scope("update_temperature"):
+                pads = [exchange_pad(v, (1, 1, 1), specs(f))
+                        for f, v in (("vx", vx), ("vy", vy), ("vz", vz))]
+                out["T"] = ops.advect_diffuse_scalar(
+                    tp, *pads, dt=dt, h=h, kappa=params["kappa"], **kw)
 
         # -- 2. divergence rhs
         with jax.named_scope("divergence"):
@@ -339,7 +407,7 @@ class NavierStokes3D:
                                                     dt=dt, h=h, **kw)
             vx_n, vy_n, vz_n = vx_n * mvx, vy_n * mvy, vz_n * mvz
 
-        return dict(state, vx=vx_n, vy=vy_n, vz=vz_n, p=p_new)
+        return dict(state, vx=vx_n, vy=vy_n, vz=vz_n, p=p_new, **out)
 
     def make_step(self) -> Callable[[dict], dict]:
         """Jitted global step (shard_map'd when a mesh decomposes the grid).
@@ -433,12 +501,15 @@ class NavierStokes3D:
         cfl = umax * params["dt"] / c.h
         # sentinel without boolean volume reduces: NaN/Inf in any velocity
         # poisons umax or ke (max and sum both propagate non-finites); the
-        # pressure — untouched by the three stats above — contributes one
-        # cheap mean-of-field sum
-        psum = state["p"]
-        for _ in range(3):
-            psum = psum.sum(axis=-1)
-        finite = jnp.isfinite(div_linf + ke + umax + psum)
+        # other fields (p, and T where the case carries it) — untouched by
+        # the three stats above — contribute one cheap sum each
+        others = div_linf + ke + umax
+        for f in self.FIELDS[3:]:
+            fsum = state[f]
+            for _ in range(3):
+                fsum = fsum.sum(axis=-1)
+            others = others + fsum
+        finite = jnp.isfinite(others)
         finite = finite.astype(jnp.float32)
         if axes:
             finite = lax.pmin(finite, axes)
@@ -452,9 +523,9 @@ class NavierStokes3D:
         host syncs in analysis code)."""
         fields = [state[f] for f in self.FIELDS]
         if self._health_jit is None:
-            def local(vx, vy, vz, p):
-                return self.health_diagnostics(
-                    {"vx": vx, "vy": vy, "vz": vz, "p": p})
+            def local(*fields):
+                return self.health_diagnostics(dict(zip(self.FIELDS,
+                                                        fields)))
 
             if self.driver.mesh is None:
                 self._health_jit = jax.jit(local)
@@ -463,7 +534,8 @@ class NavierStokes3D:
 
                 spec = self.domain.pspec()
                 self._health_jit = jax.jit(jax.shard_map(
-                    local, mesh=self.driver.mesh, in_specs=(spec,) * 4,
+                    local, mesh=self.driver.mesh,
+                    in_specs=(spec,) * len(self.FIELDS),
                     out_specs=PartitionSpec(), check_vma=False))
         vec = np.asarray(self._health_jit(*fields))
         return {k: float(v) for k, v in zip(HEALTH_DIAGS, vec)}

@@ -85,3 +85,8 @@ def project_velocity(vx, vy, vz, p, *, dt, h, **kw):
         dt=dt, h=h, **kw)
     return out["vx"], out["vy"], out["vz"]
 
+
+def advect_diffuse_scalar(T, vx, vy, vz, *, dt, h, kappa, **kw):
+    return apply_kernel(
+        "ADVECT_DIFFUSE_SCALAR", {"T": T, "vx": vx, "vy": vy, "vz": vz},
+        dt=dt, h=h, kappa=kappa, **kw)["T"]

@@ -91,3 +91,17 @@ def project_velocity(vx, vy, vz, p, *, dt, h):
     return (vx - s * (pc(1, 0, 0) - pc()),
             vy - s * (pc(0, 1, 0) - pc()),
             vz - s * (pc(0, 0, 1) - pc()))
+
+
+def advect_diffuse_scalar(T, vx, vy, vz, *, dt, h, kappa):
+    """Scalar advection-diffusion; all inputs padded (1,1,1) symmetric,
+    velocities on the cells' right faces."""
+    c = lambda f, *o: _i(f, off=o or (0, 0, 0))
+    Tc = c(T)
+    ih = 1.0 / h
+    adv = 0.0
+    for v, off in ((vx, (1, 0, 0)), (vy, (0, 1, 0)), (vz, (0, 0, 1))):
+        back = tuple(-o for o in off)
+        adv = adv + (c(v) * (0.5 * (Tc + c(T, *off)))
+                     - c(v, *back) * (0.5 * (c(T, *back) + Tc))) * ih
+    return Tc + dt * (kappa * laplacian(T, h) - adv)

@@ -8,6 +8,8 @@ generate for the Navier-Stokes code (Listing 1 declares UPDATE_VELOCITY):
   DIVERGENCE        cell-centered divergence of the face velocity field
   JACOBI_PRESSURE   one weighted-Jacobi sweep of the pressure Poisson eq.
   PROJECT_VELOCITY  pressure-gradient correction of the face velocities
+  ADVECT_DIFFUSE_SCALAR  advection (face-mean fluxes) + diffusion of a
+                    cell-centred scalar (the temperature of convection)
 
 Grid convention (staggered MAC):
   p[i,j,k]  at cell center ((i+.5)h, (j+.5)h, (k+.5)h)
@@ -65,6 +67,15 @@ PROJECT_VELOCITY = descriptor(
     velocity=dict(names=("vx", "vy", "vz"), intent="SEPARATEINOUT", cached=False),
     pressure=dict(names=("p",), intent="IN", cached=True),
     parameters=("dt", "h"),
+)
+
+ADVECT_DIFFUSE_SCALAR = descriptor(
+    "ADVECT_DIFFUSE_SCALAR",
+    stencil=(1, 1, 1, 1, 1, 1),
+    tile=(8, 8, 8),
+    scalar=dict(names=("T",), intent="SEPARATEINOUT", cached=True),
+    velocity=dict(names=("vx", "vy", "vz"), intent="IN", cached=True),
+    parameters=("dt", "h", "kappa"),
 )
 
 
@@ -179,15 +190,41 @@ def project_velocity_body(ctx: KernelContext) -> dict:
     }
 
 
+def advect_diffuse_scalar_body(ctx: KernelContext) -> dict:
+    """Explicit step of a cell-centred scalar T: T' = T + dt (kappa lap T
+    - div(u T)), the flux through each face its velocity times the mean
+    of the two cells that share it."""
+    T = ctx["T"]
+    dt, h, kappa = ctx.param("dt"), ctx.param("h"), ctx.param("kappa")
+    ih = 1.0 / h
+
+    def flux_difference(v, off):
+        back = tuple(-o for o in off)
+        hi = v.c * (0.5 * (T.c + T.at(*off)))
+        lo = v.at(*back) * (0.5 * (T.at(*back) + T.c))
+        return (hi - lo) * ih
+
+    adv = (flux_difference(ctx["vx"], (1, 0, 0))
+           + flux_difference(ctx["vy"], (0, 1, 0))
+           + flux_difference(ctx["vz"], (0, 0, 1)))
+    lap = (
+        T.at(1, 0, 0) + T.at(-1, 0, 0) + T.at(0, 1, 0) + T.at(0, -1, 0)
+        + T.at(0, 0, 1) + T.at(0, 0, -1) - 6.0 * T.c
+    ) * (ih * ih)
+    return {"T": T.c + dt * (kappa * lap - adv)}
+
+
 BODIES = {
     "UPDATE_VELOCITY": update_velocity_body,
     "DIVERGENCE": divergence_body,
     "JACOBI_PRESSURE": jacobi_pressure_body,
     "PROJECT_VELOCITY": project_velocity_body,
+    "ADVECT_DIFFUSE_SCALAR": advect_diffuse_scalar_body,
 }
 DESCRIPTORS = {
     "UPDATE_VELOCITY": UPDATE_VELOCITY,
     "DIVERGENCE": DIVERGENCE,
     "JACOBI_PRESSURE": JACOBI_PRESSURE,
     "PROJECT_VELOCITY": PROJECT_VELOCITY,
+    "ADVECT_DIFFUSE_SCALAR": ADVECT_DIFFUSE_SCALAR,
 }

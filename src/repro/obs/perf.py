@@ -168,34 +168,38 @@ def streamed_sweeps_per_step(config, local_shape, platform: str) -> int:
     outgrows what XLA stages on chip, its planes are whole (8, 128) tiles
     and y and z are periodic on no mesh axis (``jacobi.streams``); none
     elsewhere."""
-    from repro.cfd.ns3d import PERIODIC_CASES
+    from repro.cfd.ns3d import periodic_axes
     from repro.core.halo import AxisSpec
     from repro.kernels.jacobi import streams
 
     if platform != "tpu" or config.fused_sweeps > 1 or \
             (config.template or "JNP") != "JNP":
         return 0
-    # the solver's domain: z always periodic, x and y in periodic cases
-    periodic = config.case in PERIODIC_CASES
     split = dict(config.decomposition)
-    specs = [AxisSpec(a, split.get(a), periodic or a == 2) for a in range(3)]
+    specs = [AxisSpec(a, split.get(a), periodic)
+             for a, periodic in enumerate(periodic_axes(config.case))]
     return max(config.jacobi_iters, 1) if streams(local_shape, specs) else 0
 
 
 def _step_exchanges(config) -> list[tuple[tuple, int, bool]]:
     """``(widths, calls, padded)`` of the ghost fills of ONE ns3d step, in
     the order of ``NavierStokes3D._step_local``: three velocity fields at
-    widths (1,1,1); three one-sided divergence pads ((1,0),)*3; the
+    widths (1,1,1), and where the case carries a temperature
+    (``ns3d.SCALAR_CASES``) four more: T, then the three velocities again
+    for its advection; three one-sided divergence pads ((1,0),)*3; the
     Jacobi loop — ``max(jacobi_iters // max(fused_sweeps,1), 1)``
     iterations filling ``p``'s one-wide faces, padded or not
     (:func:`padded_sweeps_per_step`), or, when the communication-avoiding
     smoother is on, padding ``p`` and ``rhs`` at the sweep width; one
     one-sided projection pad ((0,1),)*3."""
+    from repro.cfd.ns3d import SCALAR_CASES
+
     k = max(config.fused_sweeps, 1)
     iters = max(config.jacobi_iters // k, 1)
     sweeps = (((1, 1, 1), iters, padded_sweeps_per_step(config) > 0)
               if k <= 1 else ((k, k, k), 2 * iters, True))
-    return [((1, 1, 1), 3, True), (((1, 0),) * 3, 3, True), sweeps,
+    full = 3 + 4 * (config.case in SCALAR_CASES)
+    return [((1, 1, 1), full, True), (((1, 0),) * 3, 3, True), sweeps,
             (((0, 1),) * 3, 1, True)]
 
 

@@ -27,7 +27,7 @@ from typing import Any, Callable, Mapping
 import jax.numpy as jnp
 import numpy as np
 
-from repro.cfd.ns3d import CFDConfig, NavierStokes3D
+from repro.cfd.ns3d import PLATE_T, CFDConfig, NavierStokes3D
 from repro.core.schedule import Schedule
 
 
@@ -332,5 +332,98 @@ def kelvin_helmholtz() -> Scenario:
                    "eps": ParamSpec(0.05, "vy perturbation amplitude")},
         init_fields=_kh_init,
         analyses={"amplitude": _kh_amplitude,
+                  "kinetic_energy": _kinetic_energy},
+    )
+
+
+# -- Rayleigh-Benard: a temperature the scenario owns -------------------------
+def _rb_builder(n: int = 48, nz: int | None = None, aspect: int = 6,
+                ra: float = 1e6, pr: float = 0.7, dt: float | None = None,
+                **kw) -> CFDConfig:
+    """A layer ``aspect`` times as wide as deep, in free-fall units: the
+    depth is 1 over ``n // aspect`` cubic cells, y and z are ``n`` and
+    ``nz or n`` cells wide; ``ra`` and ``pr`` set nu and kappa."""
+    nx = n // aspect
+    h = 1.0 / nx
+    nu, kappa = math.sqrt(pr / ra), 1.0 / math.sqrt(ra * pr)
+    dt = dt if dt is not None else min(0.25 * h,
+                                       0.2 * h * h / (6 * max(nu, kappa)))
+    kw.setdefault("jacobi_iters", 60)
+    return CFDConfig(shape=(nx, n, nz or n), extent=1.0, nu=nu, kappa=kappa,
+                     dt=dt, case="rayleigh_benard", **kw)
+
+
+# low modes (in y, in z) of the seeded temperature perturbation
+_RB_MODES = ((1, 0), (0, 1), (1, 1), (2, 1), (1, 2))
+
+
+def _rb_init(solver, state, *, seed: int, amplitude: float) -> dict:
+    """The conductive profile T = 1/2 - x, plus (amplitude / 4) sin(pi x)
+    times a seeded mix of low modes in y and z, and two seeded rolls of
+    speed ``amplitude``, a whole number of pairs across the width:
+    vx = A sin(pi x) (cos(k y + phi) + cos(k z + psi)) (0 on the top
+    plate's faces, which the mask holds), vy = -A (pi / k) cos(pi x)
+    sin(k y + phi), and vz likewise in z.
+    With k = pi (an aspect ratio of 2, 4, 6, ...) the rolls are
+    divergence-free on the grid too.  Fields are sampled at their own
+    positions (T at centres, each velocity on its face)."""
+    c = solver.config
+    h = c.h
+    nx, ny, nz = c.shape
+    rng = np.random.default_rng(seed)
+    phi, psi = rng.uniform(0.0, 2 * math.pi, size=2)
+    weights = rng.uniform(-1.0, 1.0, size=len(_RB_MODES))
+    weights /= np.abs(weights).sum()
+    thetas = rng.uniform(0.0, 2 * math.pi, size=len(_RB_MODES))
+    k = 2 * math.pi * max(round(ny * h / 2), 1) / (ny * h)
+    k0y, k0z = 2 * math.pi / (ny * h), 2 * math.pi / (nz * h)
+    x, y, z = solver.driver.coords()
+    mix = sum(w * jnp.cos(a * k0y * y + b * k0z * z + t)
+              for w, (a, b), t in zip(weights, _RB_MODES, thetas))
+    a = amplitude
+    T = 0.5 - x + 0.25 * a * jnp.sin(math.pi * x) * mix
+    vx = a * jnp.sin(math.pi * (x + 0.5 * h)) * (jnp.cos(k * y + phi)
+                                                  + jnp.cos(k * z + psi))
+    vy = -a * (math.pi / k) * jnp.cos(math.pi * x) * jnp.sin(
+        k * (y + 0.5 * h) + phi)
+    vz = -a * (math.pi / k) * jnp.cos(math.pi * x) * jnp.sin(
+        k * (z + 0.5 * h) + psi)
+    fields = dict(vx=vx * state["mask_vx"], vy=vy, vz=vz, T=T)
+    return dict(state, **{f: v.astype(jnp.float32)
+                          for f, v in fields.items()})
+
+
+def _rb_nusselt(solver, state, ctx):
+    """The conductive heat flux -dT/dx through each plate, its mean over
+    the plate, in units of the conductive state's (1 there): the
+    one-sided difference between the plate's temperature and the
+    adjacent row of cells, half a cell away."""
+    import jax
+
+    h = solver.config.h
+    T = state["T"]
+    hot, cold = PLATE_T
+    bottom = jnp.mean(hot - T[0]) / (0.5 * h)
+    top = jnp.mean(T[-1] - cold) / (0.5 * h)
+    bottom, top = (float(v) for v in jax.device_get((bottom, top)))
+    return {"bottom": bottom, "top": top, "mean": 0.5 * (bottom + top)}
+
+
+@register_scenario
+def rayleigh_benard() -> Scenario:
+    return Scenario(
+        name="rayleigh_benard",
+        description="Boussinesq convection between rigid isothermal "
+                    "plates heated from below (x vertical), y and z "
+                    "periodic: a temperature field with buoyancy",
+        builder=_rb_builder,
+        params={"ra": ParamSpec(1e6, "Rayleigh number"),
+                "pr": ParamSpec(0.7, "Prandtl number")},
+        ic_params={"seed": ParamSpec(0, "seed of the perturbation's "
+                                        "phases and mode weights"),
+                   "amplitude": ParamSpec(0.2, "roll speed; T's "
+                                               "perturbation is a quarter")},
+        init_fields=_rb_init,
+        analyses={"nusselt": _rb_nusselt,
                   "kinetic_energy": _kinetic_energy},
     )

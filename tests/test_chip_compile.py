@@ -238,6 +238,29 @@ def test_jnp_step_streams_lane_aligned_periodic_sweeps(
     _assert_sweeps_stream(compiled, shape, streamed)
 
 
+def test_convection_layer_compiles_fits_and_streams(one_chip):
+    """The ``rb-g6-dns`` step, 128 x 768 x 768 with walls in x and a
+    temperature, at deployment size: it fits one chip, and each of its
+    60 sweeps is the streamed kernel fed the walls' Neumann faces, with no
+    p-sized copy in the loop."""
+    from repro import api
+
+    cfg = api.runtime(n=768, nz=768).configure("rayleigh_benard")
+    solver = NavierStokes3D(cfg)
+    state = _shapes(jax.eval_shape(solver.init_state), one_chip)
+    assert set(state) >= {"T", "vx", "p"}
+    params = {k: jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+              for k in PARAM_KEYS}
+    compiled = jax.jit(solver._step_local).lower(state, params).compile()
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert 0 < used <= V5E.hbm_bytes
+    assert perf.streamed_sweeps_per_step(cfg, cfg.shape, "tpu") == 60
+    _assert_sweeps_stream(compiled, cfg.shape, True)
+    assert "/update_temperature/" in compiled.as_text()
+
+
 def test_x_split_768_sweeps_stream_with_the_same_halo(topo):
     """768^3 split in x over the described 2x2: each sweep is the
     streamed kernel fed the two permuted x faces, with no p-sized copy in

@@ -102,16 +102,24 @@ def test_meshless_step_is_untouched_by_the_scope(monkeypatch):
     assert spans == [{}]
 
 
-@pytest.mark.parametrize("fused_sweeps", [1, 2])
-def test_permute_count_matches_the_lowered_step(fused_sweeps):
+@pytest.mark.parametrize("case, fused_sweeps, shards", [
+    ("cavity", 1, 2), ("cavity", 2, 2),
+    # walls in x split over four: the edge shards fill the plates' ghosts
+    ("rayleigh_benard", 1, SHARDS),
+], ids=["1", "2", "rayleigh_benard-x4"])
+def test_permute_count_matches_the_lowered_step(case, fused_sweeps, shards):
     """The trip-count-aware count of the collective-permutes of the
-    decomposed step, lowered over an abstract mesh, is the analytic one."""
-    cfg = CFDConfig(shape=(N, N, N), extent=1.0, case="cavity",
+    decomposed step, lowered over an abstract mesh, is the analytic one,
+    and so are their bytes."""
+    cfg = CFDConfig(shape=(N, N, N), extent=1.0, case=case,
                     jacobi_iters=ITERS, fused_sweeps=fused_sweeps,
                     decomposition={0: "shard"})
     text, active = perf.decomposed_step_hlo(
-        cfg, n_slots=2, mesh_axes=(("slot", 1), ("shard", 2)))
-    cost, status, _ = hlo_cost.safe_analyze(text, 2)
+        cfg, n_slots=2, mesh_axes=(("slot", 1), ("shard", shards)))
+    cost, status, _ = hlo_cost.safe_analyze(text, shards)
     assert status == "ok"
     assert cost.collective_counts["collective-permute"] == \
         perf.halo_permutes_per_step(cfg, active)
+    assert cost.collective_bytes["collective-permute"] == \
+        perf.halo_bytes_per_step(cfg, active, {"slot": 1, "shard": shards},
+                                 slots_local=2)

@@ -1,8 +1,9 @@
 """The Jacobi sweep that streams p's x-planes through VMEM
 (``jacobi.jacobi_pressure_streamed``, interpreted here) agrees with the
 unpadded jnp sweep (``jacobi_pressure_unpadded``) to float32 rounding:
-periodic, split in x over four devices with the faces ``halo`` ships,
-and vmapped over members; three whole steps with the kernel forced stay
+periodic, walled in x with the faces the Neumann rule gives, split in x
+over four devices with the faces ``halo`` ships, and vmapped over
+members; three whole steps with the kernel forced stay
 within the DNS cells' ``state_gap`` limit; and
 ``obs.perf.streamed_sweeps_per_step`` counts the sweeps that take it."""
 import dataclasses
@@ -16,8 +17,8 @@ import pytest
 from repro import api, obs
 from repro.cfd import cavity, taylor_green
 from repro.cfd import ns3d
-from repro.cfd.ns3d import NavierStokes3D
-from repro.core.halo import AxisSpec, shifted_neighbours
+from repro.cfd.ns3d import CFDConfig, NavierStokes3D
+from repro.core.halo import AxisSpec, ghost_faces, shifted_neighbours
 from repro.kernels import jacobi
 from repro.kernels.jacobi import (jacobi_pressure_streamed,
                                   jacobi_pressure_unpadded, streams)
@@ -67,6 +68,31 @@ def test_faces_are_x_outer_planes():
     _assert_within_ulps(got, want)
 
 
+def _walled_x_specs(shape):
+    """p's specs in a convection layer: Neumann walls in x, y and z
+    periodic."""
+    return NavierStokes3D(CFDConfig(shape=shape, case="rayleigh_benard")
+                          )._specs("p")
+
+
+@pytest.mark.parametrize("sweeps", [1, 3])
+def test_walled_x_sweep_matches_the_jnp_sweep(sweeps):
+    """Walls in x: the kernel's outer planes are the faces the Neumann
+    rule gives (``halo.ghost_faces``), the jnp sweep reads the same rule
+    from p's edge rows."""
+    specs = _walled_x_specs(SHAPE)
+    assert not specs[0].periodic and specs[0].mesh_axis is None
+    p, rhs = _fields(SHAPE, 7)
+    got = want = p
+    for _ in range(sweeps):
+        got = jacobi_pressure_streamed(got, rhs, ghost_faces(got, specs[0]),
+                                       h=H, omega=OMEGA, block_planes=4,
+                                       interpret=True)
+        want = jacobi_pressure_unpadded(want, shifted_neighbours(want, specs),
+                                        rhs, h=H, omega=OMEGA)
+    _assert_within_ulps(got, want)
+
+
 def test_two_vmapped_members_match():
     p, rhs = _fields((2,) + SHAPE, 2)
     got = jax.vmap(lambda a, b: jacobi_pressure_streamed(
@@ -92,7 +118,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh
 from repro.cfd import taylor_green
 from repro.cfd import ns3d
-from repro.cfd.ns3d import NavierStokes3D
+from repro.cfd.ns3d import CFDConfig, NavierStokes3D
 from repro.core.halo import ghost_faces, shifted_neighbours
 from repro.kernels.jacobi import (jacobi_pressure_streamed,
                                   jacobi_pressure_unpadded)
@@ -187,6 +213,21 @@ def test_dns_cells_stream_on_a_tpu_past_the_staged_bytes(name, local,
     assert perf.streamed_sweeps_per_step(cfg, local, "cpu") == 0
 
 
+def test_convection_cell_streams_its_walled_x_sweeps():
+    """The ``rb-g6-dns`` layer, 128 x 768 x 768 with walls in x: every
+    sweep streams on a TPU, none on the CPU."""
+    from repro import api
+
+    cfg = api.runtime(n=768, nz=768).configure("rayleigh_benard")
+    assert cfg.shape == (128, 768, 768)
+    assert streams(cfg.shape, _walled_x_specs(cfg.shape))
+    assert perf.streamed_sweeps_per_step(cfg, cfg.shape, "tpu") == 60
+    assert perf.streamed_sweeps_per_step(cfg, cfg.shape, "cpu") == 0
+    walled_y = cavity.config(768, nz=768)
+    assert perf.streamed_sweeps_per_step(walled_y, walled_y.shape,
+                                         "tpu") == 0
+
+
 def _tgv(**kw):
     return dataclasses.replace(taylor_green.config(256, nz=256), **kw)
 
@@ -210,7 +251,8 @@ def test_other_steps_keep_the_jnp_sweep(monkeypatch, cfg, local):
 def test_the_counter_is_the_steps_choice(monkeypatch):
     """The step's choice and the counter read the same predicate."""
     monkeypatch.setattr(jacobi, "_STAGED_BYTES", 0)
-    for cfg in (taylor_green.config(16, nz=128), cavity.config(16, nz=128)):
+    for cfg in (taylor_green.config(16, nz=128), cavity.config(16, nz=128),
+                CFDConfig(shape=(16, 8, 128), case="rayleigh_benard")):
         solver = NavierStokes3D(cfg)
         assert streams(cfg.shape, solver._specs("p")) == bool(
             perf.streamed_sweeps_per_step(cfg, cfg.shape, "tpu"))

@@ -56,6 +56,20 @@ class TestHaloPrediction:
             2 * 3 + 3 + 2 * cfg.jacobi_iters + 1
         # the pressure solve's global mean is an all-reduce, not a permute
         assert cost.collective_counts["all-reduce"] >= 1
+        # a convection layer split in x over four crosses its walls: T's
+        # fill and the three velocity fills of its advection add 2 x 4
+        rb = CFDConfig(shape=(16, 16, 16), extent=1.0,
+                       case="rayleigh_benard", jacobi_iters=8,
+                       decomposition={0: "shard"})
+        text, active = perf.decomposed_step_hlo(
+            rb, n_slots=4, mesh_axes=(("slot", 1), ("shard", 4)))
+        cost, status, err = hlo_cost.safe_analyze(text, 4)
+        assert status == "ok" and err is None
+        assert cost.collective_bytes["collective-permute"] == \
+            perf.halo_bytes_per_step(rb, active, {"slot": 1, "shard": 4},
+                                     slots_local=4)
+        assert cost.collective_counts["collective-permute"] == \
+            2 * 7 + 3 + 2 * rb.jacobi_iters + 1
 
     def test_fused_sweeps_widen_the_analytic_halo(self):
         """The communication-avoiding smoother (fused_sweeps=k) trades
